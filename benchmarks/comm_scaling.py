@@ -26,11 +26,11 @@ import subprocess
 import sys
 
 
-def _window_pipeline(bh, dist, n_dev, n=4096, k=4):
+def _window_pipeline(bh, dist, n_dev, n=4096, k=4, dtype="float64"):
     """k shifted windows of one sharded vector, combined: every window read
     is misaligned with the shard grid -> one allgather per read site."""
     import numpy as np
-    x = bh.asarray(np.linspace(0.0, 1.0, n))
+    x = bh.asarray(np.linspace(0.0, 1.0, n, dtype=dtype))
     dist.shard(x, n=n_dev)
     w = n - k
     acc = x[0:w] * 0.0
@@ -39,11 +39,12 @@ def _window_pipeline(bh, dist, n_dev, n=4096, k=4):
     return acc.numpy()
 
 
-def _stencil(bh, dist, n_dev, n=256, iters=2):
+def _stencil(bh, dist, n_dev, n=256, iters=2, dtype="float64"):
     """Row-sharded 2-D Jacobi sweep: the four shifted reads are halo-
     crossing window reads of the sharded grid."""
     import numpy as np
-    g = bh.asarray(np.arange(n * n, dtype=np.float64).reshape(n, n) / (n * n))
+    g = bh.asarray((np.arange(n * n, dtype=np.float64).reshape(n, n)
+                    / (n * n)).astype(dtype))
     dist.shard(g, n=n_dev)
     for _ in range(iters):
         inner = (g[1:-1, :-2] + g[1:-1, 2:]

@@ -1,7 +1,9 @@
 """The paper's 15 Benchpress benchmarks (Table I) on the lazy array API.
 
 Each entry is ``fn(iters, n) -> LazyArray-or-float`` recording one bytecode
-tape per iteration (the merge-cache amortization unit, §IV-F).  Sizes are
+tape per iteration (the merge-cache amortization unit, §IV-F).  Programs
+that take ``dtype`` default to the paper's float64; float32 is what the
+TPU's Pallas kernels compile (``chip_smoke.py``).  Sizes are
 scaled down from the paper's (CPU container; the paper used a 4-core Xeon),
 but the op structure per iteration is faithful — stencils, elementwise
 chains, reductions, triangular solves, pairwise interactions.
@@ -17,12 +19,12 @@ import numpy as np
 from repro.core import lazy as bh
 
 
-def black_scholes(iters=5, n=20000):
-    s = bh.random((n,)) * 95.0
+def black_scholes(iters=5, n=20000, dtype=np.float64):
+    s = bh.random((n,), dtype) * 95.0
     s += 5.0
     bh.flush()
     r, v, t_exp = 0.02, 0.3, 1.0
-    total = bh.zeros(())
+    total = bh.zeros((), dtype)
     for i in range(iters):
         t = t_exp + i * 0.1
         d1 = (bh.log(s / 100.0) + (r + 0.5 * v * v) * t) / (v * math.sqrt(t))
@@ -60,8 +62,8 @@ def game_of_life(iters=5, n=128):
     return live
 
 
-def heat_equation(iters=8, n=256):
-    g = bh.zeros((n, n))
+def heat_equation(iters=8, n=256, dtype=np.float64):
+    g = bh.zeros((n, n), dtype)
     g[0:1, :] = 100.0
     bh.flush()
     for _ in range(iters):
@@ -274,7 +276,7 @@ def nbody_nice(iters=3, n_planets=8, n_asteroids=256):
     return apos
 
 
-def lattice_boltzmann(iters=3, n=24):
+def lattice_boltzmann(iters=3, n=24, dtype=np.float64):
     """D3Q19 stream+collide, scaled down (paper: 3.375e6 cells)."""
     dirs = [(0, 0, 0)] + [(1, 0, 0), (-1, 0, 0), (0, 1, 0), (0, -1, 0),
                           (0, 0, 1), (0, 0, -1)] + \
@@ -282,7 +284,7 @@ def lattice_boltzmann(iters=3, n=24):
             (1, 0, 1), (-1, 0, -1), (1, 0, -1), (-1, 0, 1),
             (0, 1, 1), (0, -1, -1), (0, 1, -1), (0, -1, 1)]
     w = [1 / 3] + [1 / 18] * 6 + [1 / 36] * 12
-    f = [bh.full((n, n, n), w[i]) for i in range(19)]
+    f = [bh.full((n, n, n), w[i], dtype) for i in range(19)]
     bh.flush()
     omega = 1.0
     for _ in range(iters):

@@ -24,10 +24,10 @@ from __future__ import annotations
 
 from typing import Tuple
 
-from .base import (LoweringBackend, LoweringContext,         # noqa: F401
-                   LoweringDecision, LoweringPolicy, available_backends,
-                   get_backend, register_backend, select_lowering,
-                   unregister_backend)
+from .base import (BackendBuildError, LoweringBackend,       # noqa: F401
+                   LoweringContext, LoweringDecision, LoweringPolicy,
+                   available_backends, build_block, get_backend,
+                   register_backend, select_lowering, unregister_backend)
 from .lm import (LM_STACK, FlashAttentionBackend,            # noqa: F401
                  MambaScanBackend, RMSNormBackend)
 from .pallas import PallasBackend                            # noqa: F401

@@ -33,15 +33,19 @@ acyclic.
 from __future__ import annotations
 
 from collections import OrderedDict
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Dict, Optional, Sequence, Tuple
+
+from ...kernels import resolve_interpret
 
 
 @dataclass(frozen=True)
 class LoweringContext:
     """Executor configuration a backend may need to claim or build a block.
 
-    ``interpret`` selects Pallas interpret mode (CPU); ``mesh``/``axis``/
+    ``interpret`` selects Pallas interpret mode; it defaults to
+    :func:`repro.kernels.resolve_interpret` (compiled on a TPU, interpreted
+    elsewhere).  ``mesh``/``axis``/
     ``n_dev`` describe the device mesh for sharded lowerings (``mesh`` is
     ``None`` on single-device executors).  The context deliberately carries
     no buffers: backends compile pure functions, the executor owns the
@@ -50,7 +54,7 @@ class LoweringContext:
 
     seed: int = 0
     jit: bool = True
-    interpret: bool = True
+    interpret: bool = field(default_factory=resolve_interpret)
     mesh: object = None
     axis: Optional[str] = None
     n_dev: int = 1
@@ -77,15 +81,17 @@ class LoweringDecision:
 @dataclass(frozen=True)
 class LoweringPolicy:
     """What the executor hands the scheduler: the preference-ordered
-    candidate backend names plus the context they compile under.  The name
-    tuple is part of the merge-cache key — decisions made for one backend
-    stack are never replayed under another."""
+    candidate backend names plus the context they compile under.  The key
+    is part of the merge-cache key — decisions made for one backend stack
+    are never replayed under another, nor decisions made for interpreted
+    kernels on compiled ones (Mosaic declines more, ``mosaic_reason``)."""
 
     backends: Tuple[str, ...]
     ctx: LoweringContext
 
     def key(self) -> Tuple[str, ...]:
-        return self.backends
+        return self.backends if self.ctx.interpret \
+            else self.backends + ("mosaic",)
 
 
 class LoweringBackend:
@@ -192,6 +198,27 @@ def get_backend(name: str) -> LoweringBackend:
 
 def available_backends() -> Tuple[str, ...]:
     return tuple(_REGISTRY)
+
+
+class BackendBuildError(RuntimeError):
+    """A lowering backend failed to build a block the lower stage gave it.
+
+    Raised, never swallowed: a block silently re-run on another backend
+    would hide the device path it was decided for (DESIGN.md §14)."""
+
+    def __init__(self, backend: str, op_indices):
+        self.backend = backend
+        super().__init__(f"lowering backend {backend!r} failed to build "
+                         f"block {tuple(op_indices)!r}")
+
+
+def build_block(name: str, ops: Sequence, plan, ctx: LoweringContext):
+    """``get_backend(name).build(...)``, with any failure re-raised as a
+    :class:`BackendBuildError` naming the backend."""
+    try:
+        return get_backend(name).build(ops, plan, ctx)
+    except Exception as e:
+        raise BackendBuildError(name, plan.op_indices) from e
 
 
 # ---------------------------------------------------------------------------

@@ -36,13 +36,13 @@ def build_batch_fn(tape: Sequence, plans: Sequence,
     mapped operand on tapes with no inputs.
 
     Blocks build on the backend their ``BlockPlan.lowering`` decision
-    names, with the same degrade-to-XLA-on-builder-failure rule as the
+    names; a builder failure raises ``BackendBuildError``, as in the
     dispatch engine (the server only batches schedules whose decisions are
     vmap-safe in the first place)."""
     import jax
     import jax.numpy as jnp
 
-    from . import get_backend
+    from . import build_block
 
     work = []
     salt_off = 0
@@ -51,12 +51,7 @@ def build_batch_fn(tape: Sequence, plans: Sequence,
             continue
         ops = [tape[i] for i in p.op_indices]
         name = p.lowering.backend if p.lowering is not None else "xla"
-        try:
-            fn = get_backend(name).build(ops, p, ctx)
-        except Exception:
-            if name == "xla":
-                raise                # the floor backend must not fail silently
-            fn = get_backend("xla").build(ops, p, ctx)
+        fn = build_block(name, ops, p, ctx)
         n_rand = sum(1 for op in ops if op.opcode == "random")
         work.append((fn, p.inputs, p.outputs, salt_off, n_rand))
         salt_off += n_rand

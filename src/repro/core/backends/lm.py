@@ -69,6 +69,9 @@ class _RowKernelBackend(LoweringBackend):
 
     def claims(self, ops: Sequence, plan, ctx: LoweringContext) -> Optional[str]:
         reason = self._match(ops)
+        if reason is None and not ctx.interpret:
+            from ...kernels.fused_block.codegen import mosaic_reason
+            reason = mosaic_reason(ops)
         if reason is not None:
             return reason
         return rowblock_lower_reason(ops, plan)

@@ -41,12 +41,12 @@ def build_loop_fn(tape: Sequence, plans: Sequence,
     comes from: ``("carry", q)`` reads loop state slot ``q`` (the previous
     iteration's output ``q``), ``("inv", k)`` reads invariant ``k``.  Blocks
     build on the backend their ``BlockPlan.lowering`` decision names, with
-    the same degrade-to-XLA-on-builder-failure rule as the per-flush
-    dispatch engine."""
+    the same rule as the per-flush dispatch engine: a builder failure raises
+    ``BackendBuildError``."""
     import jax
     import jax.numpy as jnp
 
-    from . import get_backend
+    from . import build_block
 
     work = []
     salt_off = 0
@@ -55,12 +55,7 @@ def build_loop_fn(tape: Sequence, plans: Sequence,
             continue
         ops = [tape[i] for i in p.op_indices]
         name = p.lowering.backend if p.lowering is not None else "xla"
-        try:
-            fn = get_backend(name).build(ops, p, ctx)
-        except Exception:
-            if name == "xla":
-                raise                # the floor backend must not fail silently
-            fn = get_backend("xla").build(ops, p, ctx)
+        fn = build_block(name, ops, p, ctx)
         n_rand = sum(1 for op in ops if op.opcode == "random")
         work.append((fn, p.inputs, p.outputs, salt_off, n_rand))
         salt_off += n_rand

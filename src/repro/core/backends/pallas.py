@@ -7,7 +7,8 @@ VMEM.  ``claims`` is the codegen's DEL-insensitive analysis layer
 (``block_lower_reason``), so the reason slugs surfaced in per-backend
 fallback stats are exactly the documented ``codegen.REASONS``, and the
 claim answer matches what the ``tpu*`` cost models priced during
-partitioning.
+partitioning.  Compiled (non-interpret) lowering additionally declines
+what Mosaic cannot compile (``codegen.mosaic_reason``).
 
 Donation is disabled: RMW (partial-write) outputs read their base inside
 the kernel epilogue, so input buffers must outlive the call.
@@ -26,6 +27,11 @@ class PallasBackend(LoweringBackend):
 
     def claims(self, ops: Sequence, plan, ctx: LoweringContext) -> Optional[str]:
         from .base import pallas_lower_reason
+        if not ctx.interpret:
+            from ...kernels.fused_block.codegen import mosaic_reason
+            reason = mosaic_reason(ops)
+            if reason is not None:
+                return reason
         return pallas_lower_reason(ops, plan)
 
     def build(self, ops: Sequence, plan, ctx: LoweringContext):

@@ -19,9 +19,11 @@ signature)``, so iterative workloads (the paper's merge-cache scenario,
 
 from __future__ import annotations
 
+import os
 import threading
 import time
 from collections.abc import Mapping
+from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -262,6 +264,32 @@ def make_block_fn(ops: Sequence[Op], seed: int = 0):
 
 
 
+#: the compile cache's place when ``JAX_COMPILATION_CACHE_DIR`` is unset: a
+#: fixed path in the checkout, since the path is part of each entry's key
+CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def use_compile_cache() -> None:
+    """Keep JAX's persistent compilation cache for the executables the
+    runtime compiles on a TPU.  JAX itself reads
+    ``JAX_COMPILATION_CACHE_DIR`` when it is set, and this sets no other
+    directory then (nor when the caller chose one); otherwise the cache
+    goes to :data:`CHECKOUT_CACHE_DIR`.  Off the TPU it stays off: XLA:CPU
+    executables loaded from another process may be built for other CPU
+    features, and results that tests hold bitwise then drift.  Every
+    dispatch path of :class:`BlockExecutor` calls it before compiling, so
+    ``Runtime``, ``Server`` and ``LazyTransformer`` all reuse compiled
+    blocks across processes; it works even after other compiles, since it
+    re-initializes JAX's cache."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR") \
+            or jax.config.jax_compilation_cache_dir \
+            or jax.default_backend() != "tpu":
+        return
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_compilation_cache_dir", str(CHECKOUT_CACHE_DIR))
+    compilation_cache.reset_cache()
+
+
 def stats_delta(before: Mapping, after: Mapping) -> Dict:
     """Recursive ``after - before`` over (possibly nested) numeric stat
     mappings — the per-flush delta ``Runtime.flush`` records into history.
@@ -410,10 +438,10 @@ class BlockExecutor:
 
     def lowering_context(self):
         from .backends import LoweringContext
-        # Pallas interpret mode everywhere except a real TPU, where blocks
-        # compile to Mosaic kernels.
+        # interpret mode resolves per platform (repro.kernels
+        # .resolve_interpret): Mosaic kernels on a TPU, the interpreter
+        # elsewhere
         return LoweringContext(seed=self.seed, jit=self.jit,
-                               interpret=jax.default_backend() != "tpu",
                                mesh=self.mesh, axis=self.axis,
                                n_dev=self.n_dev)
 
@@ -476,12 +504,12 @@ class BlockExecutor:
 
     def _executable(self, decision, ops: Sequence[Op], plan, ctx) -> Tuple:
         """Look up (or build) the jitted executable for one decided plan.
-        Returns ``(fn, donates, decision, warm)`` — the stored decision may
-        differ from the requested one if the chosen backend's builder
-        failed and the block degraded to XLA (reason ``"error"``); ``warm``
-        is True on a cache hit (the profiler times only warm dispatches —
-        cold ones include trace+compile time)."""
-        from .backends import LoweringDecision, get_backend
+        Returns ``(fn, donates, warm)``; ``warm`` is True on a
+        cache hit (the profiler times only warm dispatches — cold ones
+        include trace+compile time).  A builder failure raises
+        :class:`~repro.core.backends.BackendBuildError` naming the backend:
+        the block never silently runs elsewhere."""
+        from .backends import build_block, get_backend
         key = self._cache_key(ops, plan, backend=decision.backend, ctx=ctx)
         with self._lock:
             cached = self._cache.get(key)
@@ -494,23 +522,12 @@ class BlockExecutor:
         with trace.span("build", backend=decision.backend,
                         n_ops=len(ops)):
             be = get_backend(decision.backend)
-            try:
-                fn = be.build(ops, plan, ctx)
-            except Exception:
-                if decision.backend == "xla":
-                    raise       # the floor backend must not fail silently
-                # builder bug: degrade to the XLA floor, not a crash
-                decision = LoweringDecision(
-                    backend="xla",
-                    declined=decision.declined
-                    + ((decision.backend, "error"),))
-                be = get_backend("xla")
-                fn = be.build(ops, plan, ctx)
+            fn = build_block(decision.backend, ops, plan, ctx)
             donate = (plan.donatable if self.jit and be.donates
                       and self.donation_enabled() else ())
             if self.jit:
                 fn = jax.jit(fn, donate_argnums=donate)
-        entry = (fn, bool(donate), decision)
+        entry = (fn, bool(donate))
         with self._lock:
             self._cache[key] = entry
         return (*entry, False)
@@ -549,6 +566,7 @@ class BlockExecutor:
         (snapshot into ``sync_store``) and DEL (free) in Bohrium order.
         Dispatch is async — nothing here blocks on device results."""
         from .backends import get_backend
+        use_compile_cache()
         tape = schedule.tape
         ctx = self.lowering_context()
         if self._empty_salts is None:
@@ -564,8 +582,8 @@ class BlockExecutor:
                     # canonical signature guarantees positional
                     # correspondence with the cached executable across
                     # flushes.
-                    fn, donates, decision, warm = self._executable(
-                        decision, ops, plan, ctx)
+                    fn, donates, warm = self._executable(decision, ops,
+                                                         plan, ctx)
                     self._account(decision, plan, donates)
                     in_bufs = []
                     for u in plan.inputs:
@@ -621,6 +639,7 @@ class BlockExecutor:
         donation and no state buffer is aliased by ``sync_store`` (a
         materialized snapshot must survive the dispatch); invariants are
         never donated."""
+        use_compile_cache()
         ctx = self.lowering_context()
         donate = False
         if self.jit and self.donation_enabled():
@@ -675,6 +694,7 @@ class BlockExecutor:
         The executable is cached under ``("serve_batch", plan key, B)`` —
         the batch width is a static shape, so each width compiles once and
         every later window of that width re-dispatches it."""
+        use_compile_cache()
         B = len(salt_rows)
         plan_key = (schedule.key if schedule.key is not None
                     else tuple(p.signature for p in schedule.blocks))

@@ -27,6 +27,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from .. import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -83,7 +85,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     softcap: Optional[float] = None,
                     scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: Optional[bool] = None) -> jnp.ndarray:
     """q: (B, Hq, Sq, D); k, v: (B, Hkv, Sk, D) with Hq % Hkv == 0."""
     b, hq, sq, d = q.shape
     _, hkv, sk, _ = k.shape
@@ -125,7 +127,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             _vmem((block_q, 1), jnp.float32),
             _vmem((block_q, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
     return out[:, :, :sq, :]
 

@@ -20,7 +20,7 @@ from .ref import reference_attention
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def attention(q, k, v, causal: bool = True, window: Optional[int] = None,
               softcap: Optional[float] = None, scale: Optional[float] = None,
-              interpret: bool = True):
+              interpret: Optional[bool] = None):
     return flash_attention(q, k, v, causal=causal, window=window,
                            softcap=softcap, scale=scale, interpret=interpret)
 

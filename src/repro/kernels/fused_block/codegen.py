@@ -61,6 +61,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from .. import resolve_interpret
+
 # the kernel body evaluates ops with the SAME jnp tables as the XLA
 # fallback (make_block_fn) — importing them is what makes the bit-identity
 # contract a structural property rather than a convention to maintain
@@ -91,9 +93,17 @@ REASONS = (
     "reduction_axis",   # reduction axis not full/leading/trailing
     "reduction_out",    # reduction output is not a whole contiguous base
     "view_conflict",    # in-block read overlaps a non-identical prior write
-    "vmem",             # one (TR=1, C) slab set still exceeds the budget
+    "vmem",             # an 8-row (or whole) slab set still exceeds the budget
     "error",            # defensive: analysis itself failed
+    # compiled (non-interpret) lowering only — see mosaic_reason
+    "mosaic_x64",       # 64-bit element type: Mosaic has no 64-bit vectors
+    "mosaic_gather",    # in-kernel gather: Mosaic lowers only 2-D gathers
+    "mosaic_opcode",    # opcode with no Pallas TPU lowering (MOSAIC_OPCODES)
 )
+
+#: opcodes the interpreter runs but Pallas cannot lower to Mosaic
+#: (``NotImplementedError: Unimplemented primitive in Pallas TPU lowering``)
+MOSAIC_OPCODES = frozenset({"erf", "reduce_prod"})
 
 
 class FusedBlockUnsupported(Exception):
@@ -412,34 +422,82 @@ def _analyze(ops: Sequence[Op]) -> _Plan:
         n_written.add(u)
         plan.nodes.append(node)
 
+    # -- layout: a block without row structure (no broadcast rows/columns,
+    # no reductions) computes on the flat domain in lane-dense rows, like a
+    # 1-D domain.  A minor dim that is not a multiple of LANE would make
+    # XLA relayout every operand into padded (R, C) tiles, and the TPU
+    # compiler's time for such a relayout grows with its size.
+    if (len(domain) >= 2 and plan.C % LANE
+            and all(o.kind in ("dense", "scalar") and not o.bcast_dims
+                    for o in plan.operands)
+            and not any(nd.red_kind for nd in plan.nodes)):
+        plan.one_d = True
+        plan.C = min(ONE_D_COLS, _round_up(N, LANE))
+        plan.R = -(-N // plan.C)
+
     # -- tiling: shrink the row slab until one grid step fits VMEM ---------
     itemsize = max((np.dtype(dt).itemsize
                     for _, dt in plan.base_meta.values()), default=8)
     R, C = plan.R, plan.C
-    TR = min(R, max(1, TILE_ELEMS // max(C, 1)))
-    if TR >= SUBLANE:
-        TR = (TR // SUBLANE) * SUBLANE
 
-    def step_bytes(tr: int) -> int:
-        units = 0.0
+    def step_blocks(tr: int) -> List[Tuple[int, int]]:
+        blocks = []
         for o in plan.operands:
             if o.kind == "table":       # whole table resident per grid step
-                units += o.core.size
-                continue
-            units += {"dense": tr * C, "row": C, "col": tr, "scalar": 1}[o.kind]
-        for s in plan.slots:
-            units += {"dense": tr * C, "window": tr * C, "red_full": 1,
-                      "red_row": C, "red_col": tr}[s.kind]
-        units += len(plan.nodes) * tr * C        # live in-register values
-        return int(units * itemsize)
+                blocks.append((1, o.core.size))
+            else:
+                blocks.append({"dense": (tr, C), "row": (1, C),
+                               "col": (tr, 1), "scalar": (1, 1)}[o.kind])
+        for sl in plan.slots:
+            blocks.append({"dense": (tr, C), "window": (tr, C),
+                           "red_full": (1, 1), "red_row": (1, C),
+                           "red_col": (tr, 1)}[sl.kind])
+        return blocks
 
-    while TR > 1 and step_bytes(TR) > VMEM_BUDGET:
-        TR = max(1, TR // 2)
-    if step_bytes(TR) > VMEM_BUDGET:
-        raise FusedBlockUnsupported("vmem", f"{step_bytes(TR)} bytes at TR=1")
-    plan.TR = TR
-    plan.G = -(-R // TR)
+    plan.TR = row_tile(R, C, step_blocks, len(plan.nodes), itemsize)
+    plan.G = -(-R // plan.TR)
     return plan
+
+
+def _tile_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """VMEM bytes of one ``(rows, cols)`` block: Mosaic lays the last two
+    dims out in whole ``(SUBLANE, LANE)`` tiles."""
+    return _round_up(rows, SUBLANE) * _round_up(cols, LANE) * itemsize
+
+
+def step_vmem_bytes(tr: int, C: int, step_blocks, n_live: int,
+                    itemsize: int) -> int:
+    """VMEM one grid step needs: every pipelined in/out block twice (Pallas
+    double-buffers them so the next step's DMA overlaps this step's
+    compute) plus ``n_live`` slab-shaped values live in the kernel body."""
+    io = sum(_tile_bytes(r, c, itemsize) for r, c in step_blocks(tr))
+    return 2 * io + n_live * _tile_bytes(tr, C, itemsize)
+
+
+def row_tile(R: int, C: int, step_blocks, n_live: int, itemsize: int) -> int:
+    """Rows per grid step of an ``(R, C)`` domain tiled as ``(TR, C)`` slabs.
+
+    Mosaic accepts a block whose second-minor dim is a multiple of
+    ``SUBLANE`` or the whole array dim, so TR is one or the other: about
+    ``TILE_ELEMS`` elements per slab, halved (in whole sublane groups)
+    until :func:`step_vmem_bytes` fits ``VMEM_BUDGET``.  Raises the
+    ``vmem`` slug when even an 8-row slab set does not fit — the columns
+    would have to be tiled, which this codegen does not do."""
+    tr = max(SUBLANE, (TILE_ELEMS // max(C, 1)) // SUBLANE * SUBLANE)
+    if tr >= R:
+        tr = R
+
+    def fits(t: int) -> bool:
+        return step_vmem_bytes(t, C, step_blocks, n_live,
+                               itemsize) <= VMEM_BUDGET
+
+    while not fits(tr):
+        if tr <= SUBLANE:
+            need = step_vmem_bytes(tr, C, step_blocks, n_live, itemsize)
+            raise FusedBlockUnsupported(
+                "vmem", f"{need} bytes at TR={tr}, C={C}")
+        tr = max(SUBLANE, (tr // 2) // SUBLANE * SUBLANE)
+    return tr
 
 
 def block_lower_reason(ops: Sequence[Op]) -> Optional[str]:
@@ -455,9 +513,58 @@ def block_lower_reason(ops: Sequence[Op]) -> Optional[str]:
         return "error"
 
 
+def mosaic_reason(ops: Sequence[Op]) -> Optional[str]:
+    """Slug for what Mosaic, the TPU kernel compiler, refuses although the
+    interpreter runs it: 64-bit element types (``mosaic_x64``), the
+    in-kernel gather (``mosaic_gather``) and :data:`MOSAIC_OPCODES`
+    (``mosaic_opcode``).  ``None`` when none occurs.  The Pallas backends
+    decline with it whenever they build compiled kernels, so such blocks
+    run on XLA by decision, never by a crash."""
+    work = [op for op in ops if not op.is_system()]
+    if any(op.opcode == "gather" for op in work):
+        return "mosaic_gather"
+    if any(op.opcode in MOSAIC_OPCODES for op in work):
+        return "mosaic_opcode"
+    for op in work:
+        for v in (*op.in_views(), *op.out_views()):
+            if v.base.dtype.itemsize == 8:
+                return "mosaic_x64"
+    return None
+
+
 # ---------------------------------------------------------------------------
 # Builder
 # ---------------------------------------------------------------------------
+
+def row_map(i):
+    """Index map of a row-slab block: grid step ``i`` owns slab ``i``.
+    The block indices are int32 — under x64 a Python ``0`` would become an
+    int64 that Mosaic cannot legalize."""
+    return i, jnp.int32(0)
+
+
+def fixed_map(i):
+    """Index map of a block every grid step revisits (broadcast operands,
+    whole tables, reduction accumulators)."""
+    return jnp.int32(0), jnp.int32(0)
+
+
+def lift_literals(node, args: List) -> List:
+    """Kernel-body operands of one node: a value operand that is a Python
+    literal takes the node's dtype when no array operand would give it
+    one.  Under x64 a weak literal alone (``copy 100.0``,
+    ``where(m, 1.0, 0.0)``) is float64 in-kernel, which aborts Mosaic's
+    layout pass; lifting it changes no bits, because the XLA path's only
+    arithmetic on such a value is the final cast to the output dtype.
+    Literals next to an array operand are left to weak-type promotion,
+    exactly as in ``make_block_fn``."""
+    first = 1 if node.opcode == "where" else 0      # the mask is no value
+    if any(tag != "lit" for tag, _ in node.terms[first:]):
+        return args
+    return [jnp.asarray(a, node.out_dtype)
+            if k >= first and tag == "lit" else a
+            for k, ((tag, _), a) in enumerate(zip(node.terms, args))]
+
 
 def _red_identity(oc: str, dtype) -> jnp.ndarray:
     dt = np.dtype(dtype)
@@ -473,7 +580,7 @@ def _red_identity(oc: str, dtype) -> jnp.ndarray:
 
 
 def build_block_kernel(ops: Sequence[Op], *, seed: int = 0,
-                       interpret: bool = True):
+                       interpret: Optional[bool] = None):
     """Compile a WSP block into one tiled Pallas kernel.
 
     Returns ``(fn, input_uids, output_uids)`` where
@@ -493,22 +600,22 @@ def build_block_kernel(ops: Sequence[Op], *, seed: int = 0,
             # the whole table in one constant-index-map block: every grid
             # step sees the full array (full VMEM residency, priced by the
             # budget check above and the cost models' gather term)
-            shape, idx = (1, o.core.size), lambda i: (0, 0)
+            shape, idx = (1, o.core.size), fixed_map
         else:
             shape, idx = {
-                "dense": ((TR, C), lambda i: (i, 0)),
-                "row": ((1, C), lambda i: (0, 0)),
-                "col": ((TR, 1), lambda i: (i, 0)),
-                "scalar": ((1, 1), lambda i: (0, 0)),
+                "dense": ((TR, C), row_map),
+                "row": ((1, C), fixed_map),
+                "col": ((TR, 1), row_map),
+                "scalar": ((1, 1), fixed_map),
             }[o.kind]
         in_specs.append(pl.BlockSpec(shape, idx))
     for s in p.slots:
         shape, idx, full = {
-            "dense": ((TR, C), lambda i: (i, 0), (R_pad, C)),
-            "window": ((TR, C), lambda i: (i, 0), (R_pad, C)),
-            "red_full": ((1, 1), lambda i: (0, 0), (1, 1)),
-            "red_row": ((1, C), lambda i: (0, 0), (1, C)),
-            "red_col": ((TR, 1), lambda i: (i, 0), (R_pad, 1)),
+            "dense": ((TR, C), row_map, (R_pad, C)),
+            "window": ((TR, C), row_map, (R_pad, C)),
+            "red_full": ((1, 1), fixed_map, (1, 1)),
+            "red_row": ((1, C), fixed_map, (1, C)),
+            "red_col": ((TR, 1), row_map, (R_pad, 1)),
         }[s.kind]
         out_specs.append(pl.BlockSpec(shape, idx))
         out_shapes.append(jax.ShapeDtypeStruct(full, s.dtype))
@@ -529,7 +636,7 @@ def build_block_kernel(ops: Sequence[Op], *, seed: int = 0,
 
         for k, node in enumerate(p.nodes):
             oc = node.opcode
-            args = [resolve(t) for t in node.terms]
+            args = lift_literals(node, [resolve(t) for t in node.terms])
             if node.red_kind is not None:
                 x = jnp.broadcast_to(args[0], (TR, C))
                 if node.red_kind == "col":
@@ -592,7 +699,7 @@ def build_block_kernel(ops: Sequence[Op], *, seed: int = 0,
 
     call = pl.pallas_call(kernel, grid=(G,), in_specs=in_specs,
                           out_specs=out_specs, out_shape=out_shapes,
-                          interpret=interpret)
+                          interpret=resolve_interpret(interpret))
 
     def _shape_operand(o: _Operand, store, rvals) -> jnp.ndarray:
         if o.source == "random":

@@ -12,7 +12,7 @@ and external callers; new code should use
 
 from __future__ import annotations
 
-from typing import Sequence
+from typing import Optional, Sequence
 
 import jax.numpy as jnp
 
@@ -22,7 +22,7 @@ from .codegen import (FusedBlockUnsupported, LANE, SUBLANE,  # noqa: F401
 
 
 def build_fused_kernel(ops: Sequence[Op], *, tile: int = 0,
-                       interpret: bool = True):
+                       interpret: Optional[bool] = None):
     """Compile a WSP block into one Pallas kernel (legacy signature).
 
     Returns ``(fn, input_uids, output_uids)`` with ``fn(*flat_bufs) ->

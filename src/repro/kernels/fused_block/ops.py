@@ -19,7 +19,7 @@ from .codegen import FusedBlockUnsupported, build_block_kernel
 
 
 def fused_block_fn(ops: Sequence[Op], *, seed: int = 0,
-                   interpret: bool = True):
+                   interpret: Optional[bool] = None):
     """Best-effort fused executable for a WSP block.
 
     Returns ``(fn, input_uids, output_uids, reason)``.  ``fn(*bufs, salts)``
@@ -33,7 +33,5 @@ def fused_block_fn(ops: Sequence[Op], *, seed: int = 0,
         return fn, ins, outs, None
     except FusedBlockUnsupported as e:
         reason = e.reason
-    except Exception:       # builder bug: degrade to the XLA path, not a crash
-        reason = "error"
     fn, ins, outs = make_block_fn(ops, seed=seed)
     return fn, ins, outs, reason

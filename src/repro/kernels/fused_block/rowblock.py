@@ -45,11 +45,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from .. import resolve_interpret
 from ...core.executor import (_BINARY, _REDUCE as _REDUCE_FN, _UNARY, _read,
                               block_io)
 from ...core.ir import COMM_OPS, REDUCTIONS, Op, View
-from .codegen import (FusedBlockUnsupported, SUBLANE, TILE_ELEMS,
-                      VMEM_BUDGET, _Operand, _classify, _whole)
+from .codegen import (FusedBlockUnsupported, _Operand, _classify, _whole,
+                      fixed_map, lift_literals, row_map, row_tile)
 
 
 @dataclass
@@ -205,25 +206,16 @@ def _analyze(ops: Sequence[Op]) -> _RowPlan:
     # -- tiling: whole rows per slab, shrink until one grid step fits VMEM --
     itemsize = max((np.dtype(dt).itemsize
                     for _, dt in plan.base_meta.values()), default=8)
-    TR = min(R, max(1, TILE_ELEMS // max(C, 1)))
-    if TR >= SUBLANE:
-        TR = (TR // SUBLANE) * SUBLANE
 
-    def step_bytes(tr: int) -> int:
-        units = 0.0
-        for o in plan.operands:
-            units += {"dense": tr * C, "row": C, "col": tr, "scalar": 1}[o.kind]
-        for kind, _, _ in plan.slots:
-            units += tr * C if kind == "dense" else tr
-        units += len(plan.nodes) * tr * C        # live in-register values
-        return int(units * itemsize)
+    def step_blocks(tr: int) -> List[Tuple[int, int]]:
+        blocks = [{"dense": (tr, C), "row": (1, C), "col": (tr, 1),
+                   "scalar": (1, 1)}[o.kind] for o in plan.operands]
+        blocks += [(tr, C) if kind == "dense" else (tr, 1)
+                   for kind, _, _ in plan.slots]
+        return blocks
 
-    while TR > 1 and step_bytes(TR) > VMEM_BUDGET:
-        TR = max(1, TR // 2)
-    if step_bytes(TR) > VMEM_BUDGET:
-        raise FusedBlockUnsupported("vmem", f"{step_bytes(TR)} bytes at TR=1")
-    plan.TR = TR
-    plan.G = -(-R // TR)
+    plan.TR = row_tile(R, C, step_blocks, len(plan.nodes), itemsize)
+    plan.G = -(-R // plan.TR)
     return plan
 
 
@@ -240,7 +232,7 @@ def rowblock_lower_reason(ops: Sequence[Op]) -> Optional[str]:
 
 
 def build_rowblock_kernel(ops: Sequence[Op], *, seed: int = 0,
-                          interpret: bool = True):
+                          interpret: Optional[bool] = None):
     """Compile a reduction-consuming block into one row-tiled Pallas kernel.
 
     Returns ``(fn, input_uids, output_uids)`` with the ``make_block_fn``
@@ -258,18 +250,18 @@ def build_rowblock_kernel(ops: Sequence[Op], *, seed: int = 0,
     in_specs, out_specs, out_shapes = [], [], []
     for o in p.operands:
         shape, idx = {
-            "dense": ((TR, C), lambda i: (i, 0)),
-            "row": ((1, C), lambda i: (0, 0)),
-            "col": ((TR, 1), lambda i: (i, 0)),
-            "scalar": ((1, 1), lambda i: (0, 0)),
+            "dense": ((TR, C), row_map),
+            "row": ((1, C), fixed_map),
+            "col": ((TR, 1), row_map),
+            "scalar": ((1, 1), fixed_map),
         }[o.kind]
         in_specs.append(pl.BlockSpec(shape, idx))
     for kind, dt, _ in p.slots:
         if kind == "dense":
-            out_specs.append(pl.BlockSpec((TR, C), lambda i: (i, 0)))
+            out_specs.append(pl.BlockSpec((TR, C), row_map))
             out_shapes.append(jax.ShapeDtypeStruct((R_pad, C), dt))
         else:                       # "red": the finished (TR, 1) row values
-            out_specs.append(pl.BlockSpec((TR, 1), lambda i: (i, 0)))
+            out_specs.append(pl.BlockSpec((TR, 1), row_map))
             out_shapes.append(jax.ShapeDtypeStruct((R_pad, 1), dt))
 
     def kernel(*refs):
@@ -289,7 +281,7 @@ def build_rowblock_kernel(ops: Sequence[Op], *, seed: int = 0,
 
         for k, node in enumerate(p.nodes):
             oc = node.opcode
-            args = [resolve(t) for t in node.terms]
+            args = lift_literals(node, [resolve(t) for t in node.terms])
             if node.is_red:
                 x = jnp.broadcast_to(args[0], (TR, C))
                 # rows are complete within the slab: the reduction finishes
@@ -312,7 +304,7 @@ def build_rowblock_kernel(ops: Sequence[Op], *, seed: int = 0,
 
     call = pl.pallas_call(kernel, grid=(G,), in_specs=in_specs,
                           out_specs=out_specs, out_shape=out_shapes,
-                          interpret=interpret)
+                          interpret=resolve_interpret(interpret))
 
     def _shape_operand(o: _Operand, store) -> jnp.ndarray:
         if o.source == "zeros":

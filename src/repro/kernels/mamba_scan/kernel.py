@@ -13,10 +13,13 @@ which is exactly the paper's array contraction applied to a scan.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .. import resolve_interpret
 
 
 def _mamba_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, y_ref, h_scr, *,
@@ -45,7 +48,8 @@ def _mamba_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, y_ref, h_scr, *,
     h_scr[...] = jax.lax.fori_loop(0, chunk, body, h_scr[...])
 
 
-def mamba_scan(x, dt, b, c, a, d, *, chunk: int = 64, interpret: bool = True):
+def mamba_scan(x, dt, b, c, a, d, *, chunk: int = 64,
+               interpret: Optional[bool] = None):
     """x, dt: (B, T, d_inner); b, c: (B, T, d_state); a: (d_inner, d_state);
     d: (d_inner,).  Returns y: (B, T, d_inner)."""
     bsz, t, d_inner = x.shape
@@ -71,7 +75,7 @@ def mamba_scan(x, dt, b, c, a, d, *, chunk: int = 64, interpret: bool = True):
         out_specs=xspec,
         out_shape=jax.ShapeDtypeStruct((bsz, n_chunks * ch, d_inner), x.dtype),
         scratch_shapes=[_vmem((d_inner, d_state), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, dt, b, c, a, d[None])
     return y[:, :t]
 

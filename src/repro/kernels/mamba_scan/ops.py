@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 
@@ -11,7 +12,8 @@ from .ref import reference_mamba
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def mamba(x, dt, b, c, a, d, chunk: int = 64, interpret: bool = True):
+def mamba(x, dt, b, c, a, d, chunk: int = 64,
+          interpret: Optional[bool] = None):
     return mamba_scan(x, dt, b, c, a, d, chunk=chunk, interpret=interpret)
 
 

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import resolve_interpret
+
 
 def _rmsnorm_kernel(x_ref, res_ref, g_ref, y_ref, resid_ref, *,
                     eps: float, plus_one: bool):
@@ -36,7 +38,7 @@ def _rmsnorm_kernel(x_ref, res_ref, g_ref, y_ref, resid_ref, *,
 def fused_add_rmsnorm(x: jnp.ndarray, residual: jnp.ndarray,
                       gamma: jnp.ndarray, *, eps: float = 1e-6,
                       plus_one: bool = False, block_rows: int = 128,
-                      interpret: bool = True):
+                      interpret: Optional[bool] = None):
     """x, residual: (..., N, D); gamma: (D,).  Returns (normed, new_residual).
 
     ``new_residual = x + residual`` is emitted too (the standard pre-norm
@@ -70,7 +72,7 @@ def fused_add_rmsnorm(x: jnp.ndarray, residual: jnp.ndarray,
             jax.ShapeDtypeStruct((n_pad, d), x.dtype),
             jax.ShapeDtypeStruct((n_pad, d), x.dtype),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x2, r2, gamma)
     return (y[:n].reshape(orig_shape), resid[:n].reshape(orig_shape))
 

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 
@@ -12,7 +13,7 @@ from .ref import reference_add_rmsnorm
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def add_rmsnorm(x, residual, gamma, eps: float = 1e-6,
-                plus_one: bool = False, interpret: bool = True):
+                plus_one: bool = False, interpret: Optional[bool] = None):
     return fused_add_rmsnorm(x, residual, gamma, eps=eps, plus_one=plus_one,
                              interpret=interpret)
 

@@ -18,10 +18,13 @@ hillclimb variant — see EXPERIMENTS.md.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .. import resolve_interpret
 
 
 def _rwkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_scr, *,
@@ -49,7 +52,8 @@ def _rwkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_scr, *,
     s_scr[...] = jax.lax.fori_loop(0, chunk, body, s_scr[...])
 
 
-def rwkv6_scan(r, k, v, w, u, *, chunk: int = 64, interpret: bool = True):
+def rwkv6_scan(r, k, v, w, u, *, chunk: int = 64,
+               interpret: Optional[bool] = None):
     """r,k,v,w: (BH, T, N); u: (N,).  Returns o: (BH, T, N).
 
     ``w`` is the per-token per-channel decay (already exp(-exp(...))'d).
@@ -74,7 +78,7 @@ def rwkv6_scan(r, k, v, w, u, *, chunk: int = 64, interpret: bool = True):
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((bh, n_chunks * c, n), r.dtype),
         scratch_shapes=[_vmem((n, n), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(r, k, v, w, u[None])
     return out[:, :t]
 

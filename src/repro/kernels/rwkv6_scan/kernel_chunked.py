@@ -22,10 +22,13 @@ when N=64 is padded/blocked — MXU work instead of VPU rank-1 updates.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .. import resolve_interpret
 
 
 def _rwkv6_chunk_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_scr, *,
@@ -68,7 +71,7 @@ def _rwkv6_chunk_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_scr, *,
 
 
 def rwkv6_chunked(r, k, v, w, u, *, chunk: int = 32,
-                  interpret: bool = True):
+                  interpret: Optional[bool] = None):
     """Same contract as ``rwkv6_scan`` (r,k,v,w: (BH,T,N); u: (N,))."""
     bh, t, n = r.shape
     c = min(chunk, t)
@@ -89,7 +92,7 @@ def rwkv6_chunked(r, k, v, w, u, *, chunk: int = 32,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((bh, n_chunks * c, n), r.dtype),
         scratch_shapes=[_vmem((n, n), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(r, k, v, w, u[None])
     return out[:, :t]
 

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 
@@ -11,7 +12,8 @@ from .ref import reference_rwkv6
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def rwkv6(r, k, v, w, u, chunk: int = 64, interpret: bool = True):
+def rwkv6(r, k, v, w, u, chunk: int = 64,
+          interpret: Optional[bool] = None):
     return rwkv6_scan(r, k, v, w, u, chunk=chunk, interpret=interpret)
 
 

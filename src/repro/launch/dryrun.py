@@ -1,5 +1,6 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=512")
 
 """Multi-pod dry-run: lower + compile every (arch × shape) cell on the
 production mesh and record the roofline inputs.
@@ -7,7 +8,7 @@ production mesh and record the roofline inputs.
 MUST be run as a module:  PYTHONPATH=src python -m repro.launch.dryrun \
     --arch qwen3-4b --shape train_4k --mesh single
 
-The two lines above run BEFORE any other import (jax locks the device count
+The lines above run BEFORE any other import (jax locks the device count
 on first init); 512 placeholder host devices back the 16×16 single-pod and
 2×16×16 multi-pod meshes.
 

@@ -7,10 +7,11 @@ import numpy as np
 import pytest
 
 from repro.core import lazy as bh
-from repro.core.backends import (LoweringBackend, LoweringContext,
-                                 available_backends, default_stack,
-                                 get_backend, register_backend,
-                                 select_lowering, unregister_backend)
+from repro.core.backends import (BackendBuildError, LoweringBackend,
+                                 LoweringContext, available_backends,
+                                 default_stack, get_backend,
+                                 register_backend, select_lowering,
+                                 unregister_backend)
 from repro.core.cache import MergeCache
 from repro.core.algorithms import partition
 from repro.core.dist import host_mesh
@@ -247,10 +248,9 @@ def test_claimant_and_pallas_tie_broken_by_stack_order():
     assert d.reason_for("mamba_scan") == "no_scan"
 
 
-def test_claimant_builder_failure_degrades_to_xla():
-    """A claimant whose build() raises must not kill the flush: the
-    executor degrades the block to the XLA floor and records the decline
-    as ("name", "error")."""
+def test_claimant_builder_failure_raises_and_names_backend():
+    """A backend whose build() raises fails the flush with a
+    BackendBuildError naming it: the block never silently runs on XLA."""
 
     class _BoomBackend(_CountingBackend):
         def build(self, ops, plan, ctx):
@@ -260,12 +260,13 @@ def test_claimant_builder_failure_degrades_to_xla():
     try:
         with fresh_runtime(algorithm="greedy", backend=("boom",)) as rt:
             x = bh.asarray(np.arange(32.0))
-            got = (x * 3.0 + 1.0).numpy()
+            with pytest.raises(BackendBuildError, match="'boom'") as exc:
+                (x * 3.0 + 1.0).numpy()
             st = rt.executor.stats
-        np.testing.assert_array_equal(got, np.arange(32.0) * 3.0 + 1.0)
-        assert st["backend_blocks"]["xla"] >= 1
-        assert st["backend_blocks"].get("boom", 0) == 0
-        assert st["backend_fallbacks"]["boom"]["error"] >= 1
+        assert exc.value.backend == "boom"
+        assert "builder exploded" in str(exc.value.__cause__)
+        assert st["backend_blocks"].get("xla", 0) == 0
+        assert "error" not in st["backend_fallbacks"].get("boom", {})
     finally:
         unregister_backend("boom")
 

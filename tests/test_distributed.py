@@ -87,7 +87,9 @@ def test_pipeline_pod_axis():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from repro.distributed.pipeline import pipeline_apply
-        mesh = jax.make_mesh((4, 2), ("pod", "data"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((4, 2), ("pod", "data"),
+                             axis_types=(AxisType.Auto,) * 2)
         n_stages, m, d = 4, 6, 16
         key = jax.random.PRNGKey(0)
         w = jax.random.normal(key, (n_stages, d, d)) * 0.3

@@ -188,3 +188,50 @@ def test_stencil_program_uses_fast_path_end_to_end():
     w = (want[1:-1, :-2] + want[1:-1, 2:] + want[:-2, 1:-1] + want[2:, 1:-1]) * 0.25
     want[1:n - 1, 1:n - 1] = w
     np.testing.assert_array_equal(got, want)
+
+
+# ---------------------------------------------------------------------------
+# persistent compilation cache placement (executor.use_compile_cache, called
+# by every dispatch path)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("case", ["env", "tpu", "cpu"])
+def test_compile_cache_dir(tmp_path, case):
+    """``JAX_COMPILATION_CACHE_DIR`` set: compiled entries land there and
+    the runtime sets no other directory.  Unset: on a TPU the cache goes to
+    the checkout's ``.jax_cache``; on the CPU it stays off."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+
+    from repro.core.executor import CHECKOUT_CACHE_DIR
+    code = textwrap.dedent(f"""
+        import jax, numpy as np
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        if {case == "tpu"}:
+            jax.default_backend = lambda: "tpu"     # steer the platform test
+            from repro.core.executor import use_compile_cache
+            use_compile_cache()
+        else:
+            from repro.core import lazy as bh
+            with bh.fresh_runtime():
+                x = bh.asarray(np.arange(64.0))
+                (x * 3.0 + 1.0).numpy()
+        print(jax.config.jax_compilation_cache_dir)
+    """)
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in ("src", env.get("PYTHONPATH", "")) if p)
+    want = tmp_path / "jax_cache"
+    if case == "env":
+        env["JAX_COMPILATION_CACHE_DIR"] = str(want)
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    got = out.stdout.strip().splitlines()[-1]
+    assert got == {"env": str(want), "tpu": str(CHECKOUT_CACHE_DIR),
+                   "cpu": "None"}[case]
+    if case == "env":
+        assert any(want.iterdir()), "no compiled entry in the env cache dir"
