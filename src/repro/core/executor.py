@@ -200,6 +200,15 @@ def _base_meta(ops: Sequence[Op]) -> Dict[int, Tuple[int, np.dtype]]:
     return meta
 
 
+def _named(fn, name: str):
+    """``fn`` under the function name ``name``, which ``jax.jit`` gives
+    the XLA module it compiles."""
+    def block(*args):
+        return fn(*args)
+    block.__name__ = block.__qualname__ = name
+    return block
+
+
 def make_block_fn(ops: Sequence[Op], seed: int = 0):
     """Build the fused function for one block.
 
@@ -504,9 +513,12 @@ class BlockExecutor:
 
     def _executable(self, decision, ops: Sequence[Op], plan, ctx) -> Tuple:
         """Look up (or build) the jitted executable for one decided plan.
-        Returns ``(fn, donates, warm)``; ``warm`` is True on a
+        Returns ``(fn, donates, name, warm)``; ``warm`` is True on a
         cache hit (the profiler times only warm dispatches — cold ones
-        include trace+compile time).  A builder failure raises
+        include trace+compile time).  ``name`` is the executable's stable
+        name, ``repro_block_<backend>_<signature digest>``, which XLA's
+        module takes (``jit_<name>``) so that a device trace names a block
+        the same way in every run.  A builder failure raises
         :class:`~repro.core.backends.BackendBuildError` naming the backend:
         the block never silently runs elsewhere."""
         from .backends import build_block, get_backend
@@ -521,13 +533,16 @@ class BlockExecutor:
         trace.instant("cache.exec", hit=False, backend=decision.backend)
         with trace.span("build", backend=decision.backend,
                         n_ops=len(ops)):
+            from .tuning.profile import signature_digest
             be = get_backend(decision.backend)
             fn = build_block(decision.backend, ops, plan, ctx)
             donate = (plan.donatable if self.jit and be.donates
                       and self.donation_enabled() else ())
+            name = (f"repro_block_{decision.backend}_"
+                    f"{signature_digest(plan.signature)[:8]}")
             if self.jit:
-                fn = jax.jit(fn, donate_argnums=donate)
-        entry = (fn, bool(donate))
+                fn = jax.jit(_named(fn, name), donate_argnums=donate)
+        entry = (fn, bool(donate), name)
         with self._lock:
             self._cache[key] = entry
         return (*entry, False)
@@ -582,8 +597,8 @@ class BlockExecutor:
                     # canonical signature guarantees positional
                     # correspondence with the cached executable across
                     # flushes.
-                    fn, donates, warm = self._executable(decision, ops,
-                                                         plan, ctx)
+                    fn, donates, name, warm = self._executable(
+                        decision, ops, plan, ctx)
                     self._account(decision, plan, donates)
                     in_bufs = []
                     for u in plan.inputs:
@@ -599,7 +614,8 @@ class BlockExecutor:
                              if salt_list else self._empty_salts)
                     timing = warm and self.profiler is not None
                     with trace.span("block", backend=decision.backend,
-                                    n_ops=len(plan.op_indices)):
+                                    n_ops=len(plan.op_indices), name=name,
+                                    cold=not warm):
                         if timing:
                             jax.block_until_ready(in_bufs)  # drain queued
                             t0 = time.perf_counter()   # work so the clock

@@ -162,7 +162,9 @@ class Runtime:
         #: the last tape handed to the scheduler (post-resharding) — what
         #: ``repro.core.obs.explain`` replays to reconstruct the decisions
         self.last_tape: Optional[List[Op]] = None
-        self._t_trace0: Optional[int] = None   # first record() of this tape
+        self._t_trace0 = 0   # first record() of this tape; 0 once ended
+        #: the open ``stage.trace`` span of this tape (tracing only)
+        self._trace_stage: Optional[trace.Detached] = None
         #: per-flush records: planning stats plus an ``"exec"`` dict of
         #: per-flush executor stat deltas (NOT cumulative totals)
         self.history: "deque[Dict]" = deque(maxlen=history_limit)
@@ -170,9 +172,12 @@ class Runtime:
     # -- recording -----------------------------------------------------
     def record(self, op: Op) -> None:
         if not self.tape:
-            # stage 1 (trace) starts here; flush() emits the retroactive
-            # ``stage.trace`` span from this timestamp
+            # stage 1 (trace) starts here; flush() ends its span
             self._t_trace0 = time.perf_counter_ns()
+            tr = trace.active()
+            if tr is not None:
+                self._trace_stage = trace.Detached(
+                    tr, "stage.trace", {"flush": self.flushes})
         # a base is pre-existing if it's on this tape already, in the buffer
         # store, or live in the deferred loop-fusion queue (DESIGN.md §16:
         # deferred outputs haven't materialized yet but logically exist)
@@ -221,6 +226,7 @@ class Runtime:
         (a SYNC, a structure change)."""
         if self._flushing:
             return
+        self.end_trace_stage()
         fus = self._loop
         if not self.tape:
             if fus is not None and fus.pending:
@@ -232,25 +238,14 @@ class Runtime:
                         fus.drain(self)
                 finally:
                     self._flushing = False
-                    dt = time.perf_counter() - t0
-                    self.flush_wall_s += dt
-                    self.executor.metrics.histogram(
-                        "runtime.flush_wall_s").observe(dt)
+                    self.flush_wall_s += time.perf_counter() - t0
             return
         self._flushing = True
         t0 = time.perf_counter()
-        t0_ns = time.perf_counter_ns()
         try:
             tape, self.tape = self.tape, []
             with trace.context(flush=self.flushes), \
                  trace.span("flush", n_ops=len(tape)) as fsp:
-                tr = trace.active()
-                if tr is not None and self._t_trace0 is not None:
-                    # stage 1 ran while the user program recorded ops; emit
-                    # it retroactively from the first record() timestamp
-                    tr.complete("stage.trace", self._t_trace0, t0_ns,
-                                {"n_ops": len(tape), "flush": self.flushes})
-                self._t_trace0 = None
                 if sharding_ever_used() and tape_has_sharding(tape):
                     # placement disagreements become explicit COMM graph
                     # nodes BEFORE partitioning, so WSP prices interconnect
@@ -296,25 +291,44 @@ class Runtime:
                 self.flushes += 1
         finally:
             self._flushing = False
-            dt = time.perf_counter() - t0
-            self.flush_wall_s += dt
-            self.executor.metrics.histogram(
-                "runtime.flush_wall_s").observe(dt)
+            self.flush_wall_s += time.perf_counter() - t0
+
+    def end_trace_stage(self) -> None:
+        """End the open ``stage.trace`` span, if any: when the flush starts,
+        or when a caller takes the tape away to run it elsewhere (the
+        server's batched path)."""
+        st, self._trace_stage = self._trace_stage, None
+        t0, self._t_trace0 = self._t_trace0, 0
+        if st is not None:
+            st.close(n_ops=len(self.tape))
+            return
+        tr = trace.active()
+        if tr is not None and t0 and self.tape:
+            # the tape's first record() came before tracing was enabled
+            tr.complete("stage.trace", t0, time.perf_counter_ns(),
+                        {"n_ops": len(self.tape), "flush": self.flushes})
 
     def materialize(self, view: View) -> np.ndarray:
         self.record(Op("sync", None, sync_bases=frozenset({view.base})))
+        fid = self.flushes
         self.flush()
-        buf = self.buffers.get(view.base.uid)
-        if buf is None:
-            buf = self.executor.sync_store[view.base.uid]
-        from .executor import _read
-        return np.asarray(_read(buf, view))
+        # the host waits here for the device to drain the queue
+        with trace.span("sync.read", flush=fid) as sp:
+            buf = self.buffers.get(view.base.uid)
+            if buf is None:
+                buf = self.executor.sync_store[view.base.uid]
+            from .executor import _read
+            out = np.asarray(_read(buf, view))
+            sp.set(bytes=out.nbytes)
+        return out
 
     def adopt(self, arr: np.ndarray) -> "LazyArray":
         """Bring host data into the runtime (no bytecode recorded)."""
-        arr = np.ascontiguousarray(arr)
-        base = BaseArray(arr.size, arr.dtype)
-        self.buffers[base.uid] = jnp.asarray(arr.reshape(-1))
+        with trace.span("adopt", flush=self.flushes) as sp:
+            arr = np.ascontiguousarray(arr)
+            sp.set(bytes=arr.nbytes)
+            base = BaseArray(arr.size, arr.dtype)
+            self.buffers[base.uid] = jnp.asarray(arr.reshape(-1))
         return LazyArray(self, View.contiguous(base, arr.shape))
 
     # -- sessions (concurrent serving, DESIGN.md §18) ------------------

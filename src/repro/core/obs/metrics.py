@@ -2,9 +2,9 @@
 (DESIGN.md §17), plus the legacy-dict facade over it.
 
 Naming scheme: dotted lowercase ``<subsystem>.<metric>`` (``executor.
-blocks_run``, ``executor.backend_blocks``, ``runtime.flush_wall_s``,
-``loop.pending``).  Labels are positional tuples declared once per metric
-(``("backend",)``, ``("backend", "reason")``); a metric value is stored per
+blocks_run``, ``executor.backend_blocks``, ``loop.pending``).  Labels are
+positional tuples declared once per metric (``("backend",)``,
+``("backend", "reason")``); a metric value is stored per
 label-value tuple, insertion-ordered, so views and snapshots render in the
 order values first appeared — exactly how the legacy dicts behaved.
 

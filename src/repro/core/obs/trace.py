@@ -12,13 +12,17 @@ When a :class:`Tracer` is installed (:func:`enable`), events accumulate in
 memory in Chrome trace-event form and export with
 :meth:`Tracer.export_chrome` — load the JSON in Perfetto
 (https://ui.perfetto.dev) or ``chrome://tracing`` to see a whole serving
-session as one timeline.  Event kinds used by the runtime:
+session as one timeline.  ``Tracer(annotate=True)`` also opens a
+``jax.profiler.TraceAnnotation("repro.<name>")`` around every span, so a
+``jax.profiler`` trace of the process shows the runtime's stages on the
+device trace's clock.  Event kinds used by the runtime:
 
 * complete spans (``ph: "X"``) — ``flush`` plus the six stages
   ``stage.trace`` / ``stage.graph`` / ``stage.partition`` /
-  ``stage.schedule`` / ``stage.lower`` / ``stage.execute``, per-block
-  ``block`` dispatches and backend ``build`` compiles;
-* instants (``ph: "i"``) — cache probes (``cache.merge``, ``cache.exec``),
+  ``stage.schedule`` / ``stage.lower`` / ``stage.execute``, the merge-cache
+  probe ``plan.lookup``, the host↔device edge (``adopt``, ``sync.read``),
+  per-block ``block`` dispatches and backend ``build`` compiles;
+* instants (``ph: "i"``) — executable-cache probes (``cache.exec``),
   loop-fuser transitions (``loop.defer`` / ``loop.arm`` / ``loop.drain`` /
   ``loop.break``) and ``profiler.sample`` measurements;
 * async pairs (``ph: "b"``/``"e"``) — ``loop.deferred``, spanning the whole
@@ -27,21 +31,22 @@ session as one timeline.  Event kinds used by the runtime:
 Per-flush trace ids ride a context overlay (:func:`context`): ``Runtime.
 flush`` sets ``flush=<n>`` once and every event emitted below it — planning,
 block dispatches, backend builds, even a loop drain triggered by a later
-flush — inherits the id in its ``args``.
+flush — inherits the id in its ``args``.  Spans opened before the flush
+(``stage.trace``, ``adopt``) and after it (``sync.read``) carry the id of
+the flush that runs their tape explicitly.
 """
 
 from __future__ import annotations
 
 import contextlib
-import functools
 import json
 import os
 import threading
 import time
-from typing import Any, Callable, Dict, Iterator, List, Optional
+from typing import Any, Dict, Iterator, List, Optional
 
-__all__ = ["Tracer", "Span", "enable", "disable", "active", "span",
-           "instant", "context", "traced", "disabled_span_overhead_ns"]
+__all__ = ["Tracer", "Span", "Detached", "enable", "disable", "active",
+           "span", "instant", "context", "disabled_span_overhead_ns"]
 
 
 class _NullSpan:
@@ -88,6 +93,56 @@ class Span:
         return None
 
 
+class _Annotated:
+    """A span that also holds a profiler annotation open: entered, the
+    annotation opens first and the span is timed inside it."""
+
+    __slots__ = ("_span", "_ann")
+
+    def __init__(self, span: Span, annotation: Any):
+        self._span = span
+        self._ann = annotation
+
+    def __enter__(self) -> Span:
+        self._ann.__enter__()
+        return self._span.__enter__()
+
+    def __exit__(self, *exc: object) -> None:
+        self._span.__exit__(*exc)
+        self._ann.__exit__(*exc)
+
+
+class Detached:
+    """A span opened in one call and closed in another: ``stage.trace``
+    opens at a tape's first ``record()`` and closes when the flush starts.
+
+    It opens through ``tracer.span`` (so a subclass that overrides
+    ``span()`` sees it) and keeps the context manager that call returned.
+    Closed on the thread that opened it, that context manager exits; closed
+    on another thread, the span is recorded retroactively with
+    :meth:`Tracer.complete` instead, so that a profiler annotation is never
+    ended off the thread that opened it."""
+
+    __slots__ = ("_tracer", "_cm", "_span", "_tid", "_t0")
+
+    def __init__(self, tracer: "Tracer", name: str,
+                 args: Optional[Dict[str, Any]] = None):
+        self._tracer = tracer
+        self._tid = threading.get_ident()
+        self._t0 = time.perf_counter_ns()
+        self._cm = tracer.span(name, args)
+        self._span = self._cm.__enter__()
+
+    def close(self, **args: Any) -> None:
+        sp = self._span
+        if threading.get_ident() == self._tid:
+            sp.set(**args)
+            self._cm.__exit__(None, None, None)
+        else:
+            self._tracer.complete(sp.name, self._t0, time.perf_counter_ns(),
+                                  dict(sp.args, **args))
+
+
 class Tracer:
     """In-memory event sink; one per :func:`enable` session.
 
@@ -95,9 +150,13 @@ class Tracer:
     timestamps in microseconds relative to the tracer's epoch, so export is
     a plain ``json.dump``.  ``max_events`` bounds memory for long serving
     sessions (oldest events are NOT evicted — recording simply stops — so
-    a truncated trace is still a valid prefix of the session)."""
+    a truncated trace is still a valid prefix of the session).
 
-    def __init__(self, max_events: int = 1_000_000):
+    ``annotate=True`` mirrors every span into the JAX profiler as
+    ``repro.<name>`` (a ``jax.profiler.TraceAnnotation``, which records
+    nothing while no profiler session runs); JAX is imported only then."""
+
+    def __init__(self, max_events: int = 1_000_000, annotate: bool = False):
         self.events: List[Dict[str, Any]] = []
         self.max_events = max_events
         self.dropped = 0
@@ -107,6 +166,10 @@ class Tracer:
         # (DESIGN.md §18) each carry their own ``flush=<n>`` without
         # bleeding ids into events another thread emits concurrently
         self._ctx_local = threading.local()
+        self.annotate = annotate
+        if annotate:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
 
     @property
     def _ctx(self) -> Dict[str, Any]:
@@ -136,14 +199,18 @@ class Tracer:
     def complete(self, name: str, t0_ns: int, t1_ns: int,
                  args: Optional[Dict[str, Any]] = None) -> None:
         """Record a finished span given raw ``perf_counter_ns`` endpoints —
-        the retroactive form ``Runtime.flush`` uses for ``stage.trace``
-        (recording happened before the flush span opened)."""
+        the retroactive form ``stage.trace`` takes where it cannot be live:
+        a :class:`Detached` span closed on another thread than the one
+        that opened it, or a tape begun before tracing was enabled."""
         ev = self._base(name, "X", t0_ns, args)
         ev["dur"] = round((t1_ns - t0_ns) / 1000.0, 3)
         self._emit(ev)
 
-    def span(self, name: str, args: Optional[Dict[str, Any]] = None) -> Span:
-        return Span(self, name, dict(args) if args else {})
+    def span(self, name: str, args: Optional[Dict[str, Any]] = None):
+        sp = Span(self, name, dict(args) if args else {})
+        if self.annotate:
+            return _Annotated(sp, self._annotation("repro." + name))
+        return sp
 
     def instant(self, name: str, args: Optional[Dict[str, Any]] = None) -> None:
         ev = self._base(name, "i", time.perf_counter_ns(), args)
@@ -229,7 +296,7 @@ def disable() -> Optional[Tracer]:
     return t
 
 
-def span(name: str, **args: Any):
+def span(name: str, /, **args: Any):
     """Open a span context manager — the universal instrumentation call.
 
     Disabled mode is ONE global load + ``is None`` test returning a shared
@@ -240,7 +307,7 @@ def span(name: str, **args: Any):
     return t.span(name, args)
 
 
-def instant(name: str, **args: Any) -> None:
+def instant(name: str, /, **args: Any) -> None:
     t = _TRACER
     if t is not None:
         t.instant(name, args)
@@ -253,23 +320,6 @@ def context(**kv: Any):
     if t is None:
         return _NULL_CONTEXT
     return t.context(**kv)
-
-
-def traced(name: Optional[str] = None) -> Callable:
-    """Decorator form: ``@traced()`` wraps the call in a span named after
-    the function (disabled mode adds one global load per call)."""
-    def deco(fn: Callable) -> Callable:
-        label = name if name is not None else fn.__qualname__
-
-        @functools.wraps(fn)
-        def wrapper(*a: Any, **kw: Any) -> Any:
-            t = _TRACER
-            if t is None:
-                return fn(*a, **kw)
-            with t.span(label):
-                return fn(*a, **kw)
-        return wrapper
-    return deco
 
 
 def disabled_span_overhead_ns(iterations: int = 200_000,

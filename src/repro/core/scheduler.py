@@ -192,18 +192,26 @@ class Scheduler:
         key: Optional[Tuple] = None
         cached = False
         if use_cache:
-            key = tape_signature(tape, algorithm, cost_model,
-                                 topology=topology,
-                                 backends=lowering.key() if lowering else (),
-                                 cost_token=model_cache_token(cost_model),
-                                 partition_backend=partition_backend)
-            entry = self.cache.get(key)
-            trace.instant("cache.merge", hit=entry is not None)
-            if entry is None and self.plan_store is not None:
-                entry = self.plan_store.load(key)
-                if entry is not None:
-                    # promote the disk hit so later flushes stay in memory
-                    self.cache.put(key, entry)
+            with trace.span("plan.lookup") as sp:
+                key = tape_signature(
+                    tape, algorithm, cost_model, topology=topology,
+                    backends=lowering.key() if lowering else (),
+                    cost_token=model_cache_token(cost_model),
+                    partition_backend=partition_backend)
+                entry = self.cache.get(key)
+                hit = "memory"
+                if entry is None:
+                    hit = "miss"
+                    if self.plan_store is not None:
+                        entry = self.plan_store.load(key)
+                        if entry is not None:
+                            # promote the disk hit so later flushes stay
+                            # in memory
+                            hit = "disk"
+                            self.cache.put(key, entry)
+                if trace.active() is not None:
+                    from .tuning.profile import signature_digest
+                    sp.set(hit=hit, key=signature_digest(key))
             if entry is not None:
                 blocks, decisions = entry
                 cached = True

@@ -183,6 +183,7 @@ class Server:
             cost_token=model_cache_token(sess.cost_model))
 
     def _submit_batched(self, sess: Runtime, arrs: Sequence[LazyArray]) -> List:
+        sess.end_trace_stage()
         tape, sess.tape = sess.tape, []
         sess._known = set()
         req = _Request(sess, tape, arrs)

@@ -5,7 +5,10 @@ propagation across loop-fused drains, and the explain report."""
 
 import json
 import os
+import re
 import sys
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -94,14 +97,6 @@ class TestTracer:
         assert len(tr.events) == 2 and tr.dropped == 3
         assert tr.to_chrome()["otherData"]["dropped_events"] == 3
 
-    def test_traced_decorator(self, tracer):
-        @trace.traced("labelled")
-        def f(a, b=1):
-            return a + b
-
-        assert f(2, b=3) == 5
-        assert tracer.events[-1]["name"] == "labelled"
-
     def test_export_chrome_roundtrip(self, tracer, tmp_path):
         trace.instant("x")
         path = str(tmp_path / "t.json")
@@ -135,7 +130,7 @@ class TestPipelineSpans:
         for stage in STAGES:
             assert stage in names, f"missing {stage}"
         assert "flush" in names and "block" in names and "build" in names
-        assert "cache.merge" in names and "cache.exec" in names
+        assert "plan.lookup" in names and "cache.exec" in names
 
     def test_events_validate_against_chrome_schema(self, tracer):
         from tools.check_trace import check_events
@@ -190,6 +185,300 @@ class TestPipelineSpans:
         phases = [e["ph"] for e in tracer.events
                   if e["name"] == "loop.deferred"]
         assert phases == ["b", "e"]
+
+
+# ---------------------------------------------------------------------------
+# host-side spans: the live trace stage, the host<->device edge, the
+# merge-cache probe, block names and the profiler mirror
+# ---------------------------------------------------------------------------
+
+class _ExitLog(trace.Tracer):
+    """A tracer subclass that overrides ``span()``, as a profiler mirror
+    does, and logs every span it opens and the thread each one exits on."""
+
+    def __init__(self):
+        super().__init__()
+        self.opened = []
+        self.exits = []
+
+    def span(self, name, args=None):
+        inner = super().span(name, args)
+        self.opened.append(name)
+        exits = self.exits
+
+        class _CM:
+            def __enter__(self):
+                return inner.__enter__()
+
+            def __exit__(self, *exc):
+                exits.append((name, threading.get_ident()))
+                return inner.__exit__(*exc)
+        return _CM()
+
+
+def _events(tracer, name):
+    return [e for e in tracer.events if e["name"] == name]
+
+
+class TestHostSpans:
+    def test_stage_trace_is_live_from_first_record_to_flush_start(
+            self, tracer):
+        with fresh_runtime(loop_fusion=False) as rt:
+            x = bh.asarray(np.linspace(0.0, 1.0, 32))     # no record
+            t_first = time.perf_counter_ns()
+            y = bh.sin(x) * 2.0
+            assert rt._trace_stage is not None            # open now
+            assert not _events(tracer, "stage.trace")
+            time.sleep(0.02)
+            y.numpy()
+        st, fl = _events(tracer, "stage.trace")[0], _events(tracer,
+                                                             "flush")[0]
+        assert st["ph"] == "X"
+        assert st["ts"] >= (t_first - tracer._epoch_ns) / 1000.0 - 0.001
+        assert st["dur"] >= 20_000.0
+        assert st["ts"] + st["dur"] <= fl["ts"] + 0.002   # ends first
+        assert st["args"] == {"flush": fl["args"]["flush"],
+                              "n_ops": fl["args"]["n_ops"]}
+
+    def test_stage_trace_exits_on_its_own_thread(self):
+        tr = trace.enable(_ExitLog())
+        try:
+            with fresh_runtime(loop_fusion=False) as rt:
+                _chain(rt)
+        finally:
+            trace.disable()
+        me = threading.get_ident()
+        assert ("stage.trace", me) in tr.exits
+        assert "stage.trace" in tr.opened
+        assert _events(tr, "stage.trace")
+
+    def test_stage_trace_falls_back_to_retroactive_across_threads(self):
+        """Recording starts on one thread and the flush runs on another:
+        the span is recorded with ``complete`` and its context manager is
+        never exited off the thread that opened it."""
+        tr = trace.enable(_ExitLog())
+        try:
+            rt = bh.Runtime(loop_fusion=False)
+            with rt.activate():
+                x = bh.asarray(np.linspace(0.0, 1.0, 32))
+            got = {}
+
+            def first_records():
+                with rt.activate():
+                    got["y"] = bh.sin(x) * 2.0
+            t = threading.Thread(target=first_records)
+            t.start()
+            t.join()
+            with rt.activate():
+                out = (got["y"] + 1.0).numpy()
+        finally:
+            trace.disable()
+        np.testing.assert_allclose(
+            out, np.sin(np.linspace(0.0, 1.0, 32)) * 2.0 + 1.0)
+        assert not [e for e in tr.exits if e[0] == "stage.trace"]
+        (st,) = _events(tr, "stage.trace")
+        fl = _events(tr, "flush")[0]
+        assert st["ph"] == "X" and st["dur"] > 0
+        assert st["ts"] + st["dur"] <= fl["ts"] + 0.002
+        assert st["args"] == {"flush": fl["args"]["flush"],
+                              "n_ops": fl["args"]["n_ops"]}
+
+    def test_stage_trace_of_a_tape_begun_before_tracing(self):
+        """A tape whose first record() came before ``enable`` still gets
+        its stage, from that record() to the flush, once."""
+        with fresh_runtime(loop_fusion=False) as rt:
+            x = bh.asarray(np.linspace(0.0, 1.0, 32))
+            t_first = time.perf_counter_ns()
+            y = bh.sin(x) * 2.0
+            tr = trace.enable()
+            try:
+                y.numpy()
+            finally:
+                trace.disable()
+        (st,) = _events(tr, "stage.trace")
+        fl = _events(tr, "flush")[0]
+        first = (t_first - tr._epoch_ns) / 1000.0
+        assert first - 0.001 <= st["ts"] <= first + 1000.0
+        assert st["ts"] < 0                 # before the tracer existed
+        assert st["args"] == {"flush": fl["args"]["flush"],
+                              "n_ops": fl["args"]["n_ops"]}
+
+    def test_host_edge_and_lookup_spans_carry_flush_ids_and_args(
+            self, tracer):
+        with fresh_runtime(loop_fusion=False) as rt:
+            data = np.arange(24, dtype=np.float32).reshape(4, 6)
+            x = rt.adopt(data)
+            out = (x * 2.0).sum(axis=1).numpy()
+        np.testing.assert_allclose(out, (data * 2.0).sum(axis=1))
+        fid = _events(tracer, "flush")[0]["args"]["flush"]
+        (adopt,) = _events(tracer, "adopt")
+        assert adopt["ph"] == "X"
+        assert adopt["args"] == {"flush": fid, "bytes": data.nbytes}
+        (read,) = _events(tracer, "sync.read")
+        assert read["args"] == {"flush": fid, "bytes": out.nbytes}
+        (lookup,) = _events(tracer, "plan.lookup")
+        assert lookup["args"]["flush"] == fid
+        assert lookup["args"]["hit"] == "miss"
+        assert re.fullmatch(r"[0-9a-f]{16}", lookup["args"]["key"])
+        assert not _events(tracer, "cache.merge")
+
+    def test_repeated_tape_second_lookup_hits_memory(self, tracer):
+        with fresh_runtime(loop_fusion=False) as rt:
+            for _ in range(3):
+                _chain(rt)
+        lookups = [e["args"] for e in _events(tracer, "plan.lookup")]
+        assert lookups[0]["hit"] == "miss"
+        # the later tapes start with the DELs of the one before: the same
+        # structure from the second on, so the third replays the second
+        assert lookups[2]["hit"] == "memory"
+        assert lookups[2]["key"] == lookups[1]["key"]
+
+    def test_plan_store_lookup_reads_disk(self, tracer, tmp_path):
+        with fresh_runtime(loop_fusion=False, plan_store=str(tmp_path)) \
+                as rt:
+            _chain(rt)
+        with fresh_runtime(loop_fusion=False, plan_store=str(tmp_path)) \
+                as rt:
+            _chain(rt)
+        first, second = _events(tracer, "plan.lookup")[:2]
+        assert (first["args"]["hit"], second["args"]["hit"]) == ("miss",
+                                                                 "disk")
+        assert first["args"]["key"] == second["args"]["key"]
+
+    def test_span_overriding_subclass_sees_every_new_span(self):
+        tr = trace.enable(_ExitLog())
+        try:
+            with fresh_runtime(loop_fusion=False) as rt:
+                _chain(rt)
+        finally:
+            trace.disable()
+        for name in ("stage.trace", "adopt", "sync.read", "plan.lookup",
+                     "flush", "stage.execute", "block"):
+            assert name in tr.opened, name
+            assert _events(tr, name), name
+
+    def test_annotate_mirrors_spans_into_the_profiler(self, tmp_path):
+        import jax
+        from bench import tracefile
+
+        with fresh_runtime(loop_fusion=False) as rt:
+            _chain(rt)                          # compile outside the trace
+            bh.flush()                          # and run its DELs
+            tr = trace.enable(trace.Tracer(annotate=True))
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                with jax.profiler.TraceAnnotation(tracefile.WINDOW):
+                    _chain(rt)
+            finally:
+                jax.profiler.stop_trace()
+                trace.disable()
+        host = {e.name for e in tracefile.load(tmp_path).host}
+        for name in ("stage.trace", "adopt", "sync.read", "plan.lookup",
+                     "flush", "stage.execute"):
+            assert "repro." + name in host, name
+            assert _events(tr, name), name
+
+    def test_disabled_record_opens_no_span(self):
+        assert trace.active() is None
+        with fresh_runtime(loop_fusion=False) as rt:
+            x = bh.asarray(np.ones(8))
+            y = x * 2.0
+            assert rt.tape and rt._trace_stage is None
+            y.numpy()
+
+    def test_disabled_span_costs_about_an_empty_call(self):
+        """The disabled fast path adds one global load and an ``is None``
+        test to the call itself.  Timed against an empty function of the
+        same signature, by the same method, interleaved (the least of
+        several readings: noise only ever adds time); the absolute
+        100 ns bar is ``benchmarks/run_all.py --compare``'s, on its host."""
+        def empty(name, /, **args):
+            return None
+
+        def cost(fn, iterations=100_000, repeats=5):
+            r = range(iterations)
+            best = base = float("inf")
+            for _ in range(repeats):
+                t0 = time.perf_counter()
+                for _ in r:
+                    fn("bench")
+                best = min(best, time.perf_counter() - t0)
+                t0 = time.perf_counter()
+                for _ in r:
+                    pass
+                base = min(base, time.perf_counter() - t0)
+            return (best - base) / iterations * 1e9
+
+        assert trace.active() is None
+        spans, empties = [], []
+        for _ in range(4):
+            spans.append(cost(trace.span))
+            empties.append(cost(empty))
+        assert min(spans) <= 1.5 * min(empties) + 10.0
+
+    def test_block_names_are_stable_and_cold_marks_the_compile(
+            self, tracer):
+        names = []
+        for _ in range(2):
+            with fresh_runtime(loop_fusion=False) as rt:
+                for _ in range(3):
+                    _chain(rt)
+                for fn, _donates, name in rt.executor._cache.values():
+                    assert fn.__name__ == name
+            blocks = [e["args"] for e in tracer.events
+                      if e["name"] == "block"]
+            tracer.events.clear()
+            seen = set()
+            for b in blocks:
+                assert re.fullmatch(r"repro_block_xla_[0-9a-f]{8}",
+                                    b["name"])
+                assert b["cold"] == (b["name"] not in seen)
+                seen.add(b["name"])
+            names.append([b["name"] for b in blocks])
+        assert names[0] == names[1]          # the same in a new executor
+
+    def test_block_executable_takes_its_name_as_the_module_name(self):
+        import jax
+        import jax.numpy as jnp
+        from repro.core.executor import _named
+
+        fn = jax.jit(_named(lambda a: a + 1.0, "repro_block_xla_0123abcd"))
+        text = fn.lower(jnp.ones(4)).as_text()
+        assert "@jit_repro_block_xla_0123abcd" in text
+
+    def test_batched_server_path_ends_each_request_trace_stage(
+            self, tracer):
+        from repro.core.serve import Server
+
+        srv = Server(window_s=0.25, max_batch=2)
+        barrier = threading.Barrier(2)
+
+        def worker(i):
+            barrier.wait()
+            srv.submit(i, lambda: bh.arange(32) * 2.0 + 1.0)
+        threads = [threading.Thread(target=worker, args=(i,))
+                   for i in range(2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        assert srv.metrics.counter("serve.batches").get() == 1
+        # each request's tape left its session for the batch: its trace
+        # stage ended there, on the thread that recorded it
+        stages = [e for e in _events(tracer, "stage.trace")
+                  if e["args"]["n_ops"] > 0]
+        assert len(stages) == 2
+
+    def test_solo_server_request_has_one_trace_stage(self, tracer):
+        """A group of one runs its tape through the session's own flush
+        after the tape left and came back: its stage is recorded once."""
+        from repro.core.serve import Server
+
+        srv = Server(window_s=0.0, max_batch=2)
+        out = srv.submit(0, lambda: bh.arange(32) * 2.0 + 1.0)
+        np.testing.assert_allclose(out, np.arange(32) * 2.0 + 1.0)
+        assert srv.metrics.counter("serve.singles").get() == 1
+        assert len(_events(tracer, "stage.trace")) == 1
 
 
 # ---------------------------------------------------------------------------
@@ -369,12 +658,6 @@ class TestExecutorMetrics:
             c = ex.metrics.get("executor.blocks_run")
             assert c is not None and c.get() == ex.stats["blocks_run"]
             assert "executor.backend_blocks" in ex.metrics.names()
-
-    def test_flush_wall_histogram_observes(self):
-        with fresh_runtime(algorithm="greedy") as rt:
-            _chain(rt)
-            h = rt.executor.metrics.get("runtime.flush_wall_s")
-            assert h is not None and h.summary()["count"] >= 1
 
     def test_history_exec_deltas_sum_to_live_stats(self):
         with fresh_runtime(algorithm="greedy", loop_fusion=False) as rt:
