@@ -1,7 +1,8 @@
 """The ``xla`` lowering backend — the always-available floor.
 
 Wraps ``executor.make_block_fn``: one straight-line jitted JAX program per
-block, with every view lowered to static reshape/slice/gather constants.
+block, with every view lowered statically (``executor._view_lowering``:
+a reshape, a slice, a slice transposed or broadcast, or an index gather).
 It claims every block (COMM ops execute as identity placement casts on a
 single device), so it is the terminal fallback of every policy.
 

@@ -87,7 +87,7 @@ def _slice_plan(v: View) -> Optional[Tuple[Tuple[int, ...], Tuple[int, ...],
     sh = [s for s, st in zip(v.shape, v.strides) if s != 1]
     st = [st for s, st in zip(v.shape, v.strides) if s != 1]
     if any(s <= 0 for s in st):
-        return None                       # broadcast / reversed: gather path
+        return None                       # broadcast or reversed
     if st and st[-1] != 1:                # strided innermost dim: view the
         sh.append(1)                      # base as (..., step) and take one
         st.append(1)                      # column of it
@@ -118,24 +118,83 @@ def _slice_plan(v: View) -> Optional[Tuple[Tuple[int, ...], Tuple[int, ...],
     return tuple(dims), tuple(starts), tuple(sh)
 
 
-def _read(buf, v: View):
+def _permute_plan(v: View, write: bool = False) -> Optional[Tuple]:
+    """Lower a transposed and/or broadcast view to one static slice of a
+    reordering of it: returns ``(perm, plan)`` where ``perm`` lists the
+    view's axes that address data (non-zero stride, or size 1) by stride,
+    descending, and ``plan`` is the ``_slice_plan`` of the view so
+    reordered; None when no such reordering is a nested row-major slice
+    (negative strides, overlapping or wrapping rows).  A write has no
+    lowering through a broadcast (stride-0) axis."""
+    if v.size == 0:
+        return None
+    kept = [i for i, (s, st) in enumerate(zip(v.shape, v.strides))
+            if st != 0 or s == 1]
+    if write and len(kept) < len(v.shape):
+        return None
+    perm = tuple(sorted(kept, key=lambda i: -v.strides[i]))
+    plan = _slice_plan(View(v.base, v.offset,
+                            tuple(v.shape[i] for i in perm),
+                            tuple(v.strides[i] for i in perm)))
+    return None if plan is None else (perm, plan)
+
+
+def _view_lowering(v: View, write: bool = False) -> Tuple[str, object]:
+    """How ``_read`` (``write=False``) or ``_write`` lowers a view,
+    ``"whole" | "slice" | "permute" | "gather"``, and the static plan of
+    that lowering, tried in this order:
+
+    * ``whole``: the contiguous base, a reshape;
+    * ``slice``: ``_slice_plan``, a reshape and one static slice;
+    * ``permute``: ``_permute_plan``, that slice of the view's axes
+      reordered by stride, then a transpose back and a broadcast over the
+      stride-0 axes;
+    * ``gather``: a static int32 index array (``_view_index``) for what no
+      reordering of a nested row-major slice describes.
+    """
     if v.offset == 0 and v.size == v.base.size and v.is_contiguous():
-        return buf.reshape(v.shape)
+        return "whole", None
     plan = _slice_plan(v)
     if plan is not None:
-        dims, starts, sizes = plan
-        sub = jax.lax.slice(buf.reshape(dims), starts,
-                            tuple(a + n for a, n in zip(starts, sizes)))
-        return sub.reshape(v.shape)
+        return "slice", plan
+    plan = _permute_plan(v, write)
+    if plan is not None:
+        return "permute", plan
+    return "gather", None
+
+
+def _sliced(buf, plan):
+    dims, starts, sizes = plan
+    return jax.lax.slice(buf.reshape(dims), starts,
+                         tuple(a + n for a, n in zip(starts, sizes)))
+
+
+def _read(buf, v: View):
+    how, plan = _view_lowering(v)
+    if how == "whole":
+        return buf.reshape(v.shape)
+    if how == "slice":
+        return _sliced(buf, plan).reshape(v.shape)
+    if how == "permute":
+        perm, plan = plan
+        sub = _sliced(buf, plan).reshape(tuple(v.shape[i] for i in perm))
+        sub = jnp.transpose(sub, tuple(np.argsort(perm)))
+        kept = set(perm)
+        sub = sub.reshape(tuple(s if i in kept else 1
+                                for i, s in enumerate(v.shape)))
+        return jnp.broadcast_to(sub, v.shape)
     return buf[_view_index(v)].reshape(v.shape)
 
 
 def _write(buf, v: View, val):
     val = jnp.broadcast_to(jnp.asarray(val, buf.dtype), v.shape)
-    if v.offset == 0 and v.size == v.base.size and v.is_contiguous():
+    how, plan = _view_lowering(v, write=True)
+    if how == "whole":
         return val.reshape(-1)
-    plan = _slice_plan(v)
-    if plan is not None:
+    if how == "permute":
+        perm, plan = plan
+        val = jnp.transpose(val, perm)
+    if how in ("slice", "permute"):
         dims, starts, sizes = plan
         window = tuple(slice(a, a + n) for a, n in zip(starts, sizes))
         out = buf.reshape(dims).at[window].set(val.reshape(sizes))
@@ -215,11 +274,19 @@ def make_block_fn(ops: Sequence[Op], seed: int = 0):
     Returns ``(fn, input_uids, output_uids)`` where ``fn(*input_bufs) ->
     output_bufs`` is pure and jittable.  All view indices are static
     constants, so XLA sees one straight-line fused program per block — the
-    fusion boundary is exactly what WSP chose.
+    fusion boundary is exactly what WSP chose.  ``fn.view_lowerings``
+    counts the block's view reads and writes by ``_view_lowering``.
     """
     work = [op for op in ops if not op.is_system()]
     inputs, outputs, contracted = block_io(ops)   # DEL/SYNC drive contraction
     meta = _base_meta(work)
+    lowerings = dict.fromkeys(("whole", "slice", "permute", "gather"), 0)
+    for op in work:
+        for v in op.inputs:
+            if isinstance(v, View):
+                lowerings[_view_lowering(v)[0]] += 1
+        if op.out is not None:
+            lowerings[_view_lowering(op.out, write=True)[0]] += 1
 
     def fn(*bufs_and_salt):
         *bufs, salts = bufs_and_salt
@@ -268,6 +335,7 @@ def make_block_fn(ops: Sequence[Op], seed: int = 0):
                 env[ov.base.uid] = _write(env[ov.base.uid], ov, val)
         return tuple(env[u] for u in outputs)
 
+    fn.view_lowerings = lowerings
     return fn, inputs, outputs
 
 
@@ -513,12 +581,16 @@ class BlockExecutor:
 
     def _executable(self, decision, ops: Sequence[Op], plan, ctx) -> Tuple:
         """Look up (or build) the jitted executable for one decided plan.
-        Returns ``(fn, donates, name, warm)``; ``warm`` is True on a
-        cache hit (the profiler times only warm dispatches — cold ones
-        include trace+compile time).  ``name`` is the executable's stable
-        name, ``repro_block_<backend>_<signature digest>``, which XLA's
-        module takes (``jit_<name>``) so that a device trace names a block
-        the same way in every run.  A builder failure raises
+        Returns ``(fn, donates, name, views, warm)``; ``warm`` is True on
+        a cache hit (the profiler times only warm dispatches — cold ones
+        include trace+compile time).  ``views`` is ``(permutes, gathers)``,
+        the block's view reads and writes that its ``xla`` lowering
+        (``make_block_fn``) takes through a transpose or broadcast and
+        through an index gather; 0 and 0 on every other backend.
+        ``name`` is the executable's stable name,
+        ``repro_block_<backend>_<signature digest>``, which XLA's module
+        takes (``jit_<name>``) so that a device trace names a block the
+        same way in every run.  A builder failure raises
         :class:`~repro.core.backends.BackendBuildError` naming the backend:
         the block never silently runs elsewhere."""
         from .backends import build_block, get_backend
@@ -536,13 +608,16 @@ class BlockExecutor:
             from .tuning.profile import signature_digest
             be = get_backend(decision.backend)
             fn = build_block(decision.backend, ops, plan, ctx)
+            lowerings = getattr(fn, "view_lowerings", {})
+            views = (lowerings.get("permute", 0),
+                     lowerings.get("gather", 0))
             donate = (plan.donatable if self.jit and be.donates
                       and self.donation_enabled() else ())
             name = (f"repro_block_{decision.backend}_"
                     f"{signature_digest(plan.signature)[:8]}")
             if self.jit:
                 fn = jax.jit(_named(fn, name), donate_argnums=donate)
-        entry = (fn, bool(donate), name)
+        entry = (fn, bool(donate), name, views)
         with self._lock:
             self._cache[key] = entry
         return (*entry, False)
@@ -597,7 +672,7 @@ class BlockExecutor:
                     # canonical signature guarantees positional
                     # correspondence with the cached executable across
                     # flushes.
-                    fn, donates, name, warm = self._executable(
+                    fn, donates, name, views, warm = self._executable(
                         decision, ops, plan, ctx)
                     self._account(decision, plan, donates)
                     in_bufs = []
@@ -615,7 +690,8 @@ class BlockExecutor:
                     timing = warm and self.profiler is not None
                     with trace.span("block", backend=decision.backend,
                                     n_ops=len(plan.op_indices), name=name,
-                                    cold=not warm):
+                                    cold=not warm, permutes=views[0],
+                                    gathers=views[1]):
                         if timing:
                             jax.block_until_ready(in_bufs)  # drain queued
                             t0 = time.perf_counter()   # work so the clock

@@ -7,15 +7,21 @@ slice fast path (ISSUE 2 satellites):
   they are never donated, contracted, or dropped from outputs;
 * ``_slice_plan`` lowers single-slice regularly-strided views to static
   reshape+slice (no O(size) gather-index constants in block jaxprs), with
-  exact read/write equivalence against NumPy's own striding.
+  exact read/write equivalence against NumPy's own striding;
+* ``_permute_plan`` lowers transposed and broadcast views to that slice of
+  the view's axes reordered by stride, then a transpose and a broadcast;
+  only reversed and overlapping views still read through an index gather.
 """
+
+import itertools
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.executor import (_read, _slice_plan, _view_index, _write,
-                                 block_dead_bases, block_io)
+from repro.core.executor import (_read, _slice_plan, _view_index,
+                                 _view_lowering, _write, block_dead_bases,
+                                 block_io)
 from repro.core.ir import BaseArray, Op, View
 
 
@@ -122,28 +128,108 @@ FAST_VIEWS = [
     (36, 0, (6, 1, 6), (6, 6, 1)),   # size-1 dim with arbitrary stride
 ]
 
+#: a (1, s, h, d) = (1, 8, 4, 16) attention-head base: the LM's q/k/v
+HEAD = (1, 8, 4, 16)
+
+
+def _axes_view(b, order, offset=0, shape=None):
+    """The view of the row-major ``HEAD``-shaped base ``b`` whose axes are
+    the base's in ``order`` (``x.transpose(order)``), optionally cut to
+    ``shape`` at ``offset``."""
+    strides = View.contiguous(b, HEAD).strides
+    full = tuple(HEAD[i] for i in order)
+    return View(b, offset, shape or full, tuple(strides[i] for i in order))
+
+
+PERMUTED_VIEWS = [
+    # (base size, offset, shape, strides, also written) — a reordering of
+    # the view's axes by stride is one slice; stride-0 axes broadcast
+    (24, 0, (4, 6), (1, 4), True),             # 2-D transpose
+    (24, 0, (3, 24), (0, 1), False),           # broadcast (stride 0)
+    (24, 0, (6, 3, 4), (1, 0, 6), False),      # transpose with a broadcast
+    (24, 2, (2, 5, 3), (12, 0, 1), False),     # mid-axis broadcast, offset
+    (24, 0, (6, 1, 4), (1, 0, 6), True),       # size-1 axis of stride 0
+    (512, 0, (1, 4, 8, 16), (512, 16, 64, 1), True),   # q.transpose(0,2,1,3)
+    (512, 0, (1, 4, 16, 8), (512, 16, 1, 64), True),   # k.transpose(0,2,3,1)
+    (512, 8, (1, 4, 8, 8), (512, 16, 64, 1), True),    # half of each head
+]
+
 GATHER_VIEWS = [
-    (24, 0, (4, 6), (1, 4)),         # transpose
-    (24, 0, (3, 24), (0, 1)),        # broadcast (stride 0)
     (24, 23, (24,), (-1,)),          # reversed
     (16, 0, (4, 4), (2, 1)),         # overlapping rows (stride < width)
 ]
 
 
-@pytest.mark.parametrize("size,off,shape,strides", FAST_VIEWS)
-def test_slice_plan_read_write_match_numpy(size, off, shape, strides):
-    b = _base(size)
-    v = View(b, off, shape, strides)
-    assert _slice_plan(v) is not None
+@pytest.fixture
+def no_gather(monkeypatch):
+    """Make the executor's gather path raise (the tests' oracle keeps its
+    own reference to ``_view_index``)."""
+    import repro.core.executor as ex
+
+    def boom(v):
+        raise AssertionError(f"gather path hit for {v}")
+    monkeypatch.setattr(ex, "_view_index", boom)
+
+
+def _check_read(v, size):
     base_np = np.arange(size, dtype=np.float64)
-    buf = jnp.asarray(base_np)
-    np.testing.assert_array_equal(np.asarray(_read(buf, v)), _np_view(base_np, v))
-    val = np.full(shape, -1.0)
-    got = np.asarray(_write(buf, v, jnp.asarray(val)))
+    np.testing.assert_array_equal(
+        np.asarray(_read(jnp.asarray(base_np), v)), _np_view(base_np, v))
+
+
+def _check_write(v, size):
+    base_np = np.arange(size, dtype=np.float64)
+    val = -1.0 - np.arange(v.size, dtype=np.float64).reshape(v.shape)
+    got = np.asarray(_write(jnp.asarray(base_np), v, jnp.asarray(val)))
     want = base_np.copy()
-    want[_view_index(v) if _view_index(v) is not None
-         else slice(None)] = val.reshape(-1)
+    want[_view_index(v)] = val.reshape(-1)
     np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("size,off,shape,strides", FAST_VIEWS)
+def test_slice_plan_read_write_match_numpy(no_gather, size, off, shape,
+                                           strides):
+    v = View(_base(size), off, shape, strides)
+    assert _slice_plan(v) is not None
+    assert _view_lowering(v)[0] in ("whole", "slice")
+    assert _view_lowering(v, write=True)[0] == _view_lowering(v)[0]
+    _check_read(v, size)
+    _check_write(v, size)
+
+
+@pytest.mark.parametrize("mode", ["read", "write"])
+@pytest.mark.parametrize("size,off,shape,strides,writes", PERMUTED_VIEWS)
+def test_permuted_views_lower_without_gather(no_gather, size, off, shape,
+                                             strides, writes, mode):
+    v = View(_base(size), off, shape, strides)
+    assert _slice_plan(v) is None
+    if mode == "read":
+        assert _view_lowering(v)[0] == "permute"
+        _check_read(v, size)
+    elif writes:
+        assert _view_lowering(v, write=True)[0] == "permute"
+        _check_write(v, size)
+    else:                            # a write through a broadcast gathers
+        assert _view_lowering(v, write=True)[0] == "gather"
+
+
+@pytest.mark.parametrize("order", list(itertools.permutations(range(4))))
+def test_every_axis_order_of_a_head_view_reads_and_writes(no_gather, order):
+    b = _base(int(np.prod(HEAD)))
+    v = _axes_view(b, order)
+    assert _view_lowering(v)[0] in ("whole", "permute")
+    _check_read(v, b.size)
+    _check_write(v, b.size)
+
+
+def test_transposed_head_view_read_has_no_gather_in_its_jaxpr():
+    import jax
+    b = _base(int(np.prod(HEAD)))
+    for order in ((0, 2, 1, 3), (0, 2, 3, 1)):
+        v = _axes_view(b, order)
+        jaxpr = jax.make_jaxpr(lambda x: _read(x, v))(jnp.zeros(b.size))
+        prims = {e.primitive.name for e in jaxpr.jaxpr.eqns}
+        assert "gather" not in prims and "transpose" in prims, prims
 
 
 @pytest.mark.parametrize("size,off,shape,strides", GATHER_VIEWS)
@@ -151,6 +237,7 @@ def test_gather_views_fall_back_and_stay_correct(size, off, shape, strides):
     b = _base(size)
     v = View(b, off, shape, strides)
     assert _slice_plan(v) is None
+    assert _view_lowering(v)[0] == _view_lowering(v, write=True)[0] == "gather"
     base_np = np.arange(size, dtype=np.float64)
     np.testing.assert_array_equal(
         np.asarray(_read(jnp.asarray(base_np), v)), _np_view(base_np, v))
@@ -170,8 +257,9 @@ def test_fast_path_emits_no_gather_constants(monkeypatch):
     monkeypatch.setattr(ex, "_view_index", boom)
     _read(buf, v)                               # must use the slice plan
     _write(buf, v, jnp.zeros((4, 4)))
+    _read(buf, View(b, 0, (6, 6), (1, 6)))      # a transpose permutes
     with pytest.raises(AssertionError):
-        _read(buf, View(b, 0, (6, 6), (1, 6)))  # transpose needs gather
+        _read(buf, View(b, 0, (4, 6), (3, 1)))  # overlapping rows gather
 
 
 def test_stencil_program_uses_fast_path_end_to_end():

@@ -423,7 +423,7 @@ class TestHostSpans:
             with fresh_runtime(loop_fusion=False) as rt:
                 for _ in range(3):
                     _chain(rt)
-                for fn, _donates, name in rt.executor._cache.values():
+                for fn, _donates, name, _views in rt.executor._cache.values():
                     assert fn.__name__ == name
             blocks = [e["args"] for e in tracer.events
                       if e["name"] == "block"]
@@ -484,6 +484,46 @@ class TestHostSpans:
 # ---------------------------------------------------------------------------
 # metrics registry
 # ---------------------------------------------------------------------------
+
+class TestBlockViewLowerings:
+    """``block`` spans carry how the ``xla`` lowering read and wrote the
+    block's views: ``permutes`` (a transpose or broadcast of one slice)
+    and ``gathers`` (a static index gather)."""
+
+    def test_lm_prefill_permutes_its_head_views_and_gathers_none(
+            self, tracer):
+        import jax
+        from repro.models import transformer as T
+        from repro.models.config import ModelConfig
+        from repro.models.lazy_transformer import LazyTransformer
+
+        cfg = ModelConfig(name="lm_views", family="dense", n_layers=2,
+                          d_model=64, n_heads=4, n_kv_heads=4, d_ff=128,
+                          vocab_size=97, dtype="float32",
+                          param_dtype="float32", norm_plus_one=True,
+                          tie_embeddings=False)
+        params, _ = T.init_params(cfg, jax.random.PRNGKey(0))
+        lt = LazyTransformer(params, cfg)
+        lt.prefill(np.arange(16, dtype=np.int32)[None], 16)
+        blocks = [e["args"] for e in _events(tracer, "block")]
+        assert blocks
+        assert sum(b["gathers"] for b in blocks) == 0
+        # q, k and v to heads-major for the two attention matmuls, and
+        # the attention output back, in every layer
+        assert sum(b["permutes"] for b in blocks) >= 4 * cfg.n_layers
+        assert all(b["permutes"] == b["gathers"] == 0 for b in blocks
+                   if b["backend"] != "xla")
+
+    def test_reversed_view_reports_a_gather(self, tracer):
+        with fresh_runtime(loop_fusion=False):
+            x = bh.asarray(np.arange(8.0))
+            out = (x[::-1] * 2.0).numpy()
+        np.testing.assert_array_equal(out, np.arange(8.0)[::-1] * 2.0)
+        (block,) = _events(tracer, "block")
+        assert block["args"]["backend"] == "xla"
+        assert block["args"]["gathers"] >= 1
+        assert block["args"]["permutes"] == 0
+
 
 class TestMetricsRegistry:
     def test_counter_labels_and_get_or_create(self):
