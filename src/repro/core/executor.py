@@ -57,6 +57,19 @@ _REDUCE = {
 }
 
 
+#: opaque opcodes whose blocks ``executor.stats`` counts as
+#: ``<opcode>_blocks``
+COUNTED_OPCODES = ("argsort", "ragged_matmul")
+
+
+def _static_rows(op: Op) -> int:
+    """Rows an ``argsort`` sorts (its output's size over the sorted axis)
+    or a ``ragged_matmul`` can take (its output's rows, routed or not)."""
+    if op.opcode == "argsort":
+        return op.out.size // op.out.shape[op.axis]
+    return op.out.shape[0]
+
+
 def _view_index(v: View) -> Optional[np.ndarray]:
     """Static flat element indices of a view into its base, or None when the
     view is the whole contiguous base (fast path: pure reshape)."""
@@ -328,6 +341,18 @@ def make_block_fn(ops: Sequence[Op], seed: int = 0):
                 val = jnp.arange(op.out.size, dtype=op.out.dtype).reshape(op.out.shape)
             elif oc == "gather":
                 val = jnp.take(ins[0], ins[1].astype(jnp.int32), axis=op.axis or 0)
+            elif oc == "argsort":
+                val = jnp.argsort(ins[0], axis=op.axis, stable=True)
+            elif oc == "ragged_matmul":
+                # at the ambient matmul precision, like ``matmul``; the
+                # scope names the grouped product's ops in a device trace.
+                # The TPU kernel leaves rows past the groups' sum unwritten
+                # (the CPU's zeroes them), so they are zeroed here
+                sizes = ins[2].astype(jnp.int32)
+                with jax.named_scope("repro_ragged_matmul"):
+                    val = jax.lax.ragged_dot(ins[0], ins[1], sizes)
+                rows = jnp.arange(val.shape[0], dtype=jnp.int32)[:, None]
+                val = jnp.where(rows < sizes.sum(), val, 0)
             else:
                 raise NotImplementedError(f"opcode {oc!r}")
             ov = op.out
@@ -447,12 +472,18 @@ class BlockExecutor:
         self.backends: Tuple[str, ...] = default_stack(backend, mesh)
         self._cache: Dict[Tuple, Tuple] = {}
         self._decisions: Dict[Tuple, object] = {}
+        #: executable name -> ``(opcode, static rows)`` of its block's op
+        #: of ``COUNTED_OPCODES``
+        self._counted: Dict[str, Tuple[str, int]] = {}
         #: guards the executable/decision caches under concurrent flushes
         #: (DESIGN.md §18).  Builds happen OUTSIDE the lock — two threads
         #: racing a cold key may both compile; last put wins, both work.
         self._lock = threading.RLock()
         self._empty_salts = None
         self.sync_store: Dict[int, jnp.ndarray] = {}
+        #: group sizes of the ``ragged_matmul`` blocks run under tracing,
+        #: by base uid, until ``emit_group_rows`` reads them
+        self._group_sizes: Dict[int, jnp.ndarray] = {}
         #: the single backing store for every executor observation
         #: (DESIGN.md §17); ``stats`` is a legacy-dict-shaped live view
         self.metrics = MetricsRegistry()
@@ -487,6 +518,8 @@ class BlockExecutor:
             st.declare_group("pallas_fallbacks", ("reason",))
             for key in ("loop_flushes", "loop_iterations"):
                 st.declare_scalar(key)
+            for oc in COUNTED_OPCODES:
+                st.declare_scalar(f"{oc}_blocks")
             st.declare_group("backend_blocks", ("backend",),
                              presets=self.backends)
             st.declare_group("backend_fallbacks", ("backend", "reason"),
@@ -617,9 +650,13 @@ class BlockExecutor:
                     f"{signature_digest(plan.signature)[:8]}")
             if self.jit:
                 fn = jax.jit(_named(fn, name), donate_argnums=donate)
+        counted = next(((op.opcode, _static_rows(op)) for op in ops
+                        if op.opcode in COUNTED_OPCODES), None)
         entry = (fn, bool(donate), name, views)
         with self._lock:
             self._cache[key] = entry
+            if counted is not None:
+                self._counted[name] = counted
         return (*entry, False)
 
     def _account(self, decision, plan, donates: bool) -> None:
@@ -675,6 +712,13 @@ class BlockExecutor:
                     fn, donates, name, views, warm = self._executable(
                         decision, ops, plan, ctx)
                     self._account(decision, plan, donates)
+                    counted = self._counted.get(name)
+                    extra = {}
+                    if counted is not None:
+                        self.stats.inc(f"{counted[0]}_blocks")
+                        extra = {"opcode": counted[0], "rows": counted[1]}
+                        if counted[0] == "ragged_matmul" and trace.active():
+                            self._keep_group_sizes(ops, buffers)
                     in_bufs = []
                     for u in plan.inputs:
                         if u not in buffers:
@@ -691,7 +735,7 @@ class BlockExecutor:
                     with trace.span("block", backend=decision.backend,
                                     n_ops=len(plan.op_indices), name=name,
                                     cold=not warm, permutes=views[0],
-                                    gathers=views[1]):
+                                    gathers=views[1], **extra):
                         if timing:
                             jax.block_until_ready(in_bufs)  # drain queued
                             t0 = time.perf_counter()   # work so the clock
@@ -711,6 +755,33 @@ class BlockExecutor:
                             self.sync_store[b.uid] = buffers[b.uid]
                     for b in op.del_bases:
                         buffers.pop(b.uid, None)
+
+    def _keep_group_sizes(self, ops: Sequence[Op], buffers) -> None:
+        """Under tracing: keep a device copy of a ``ragged_matmul`` block's
+        group sizes (a later block may donate the buffer), once per base,
+        for :meth:`emit_group_rows`."""
+        (op,) = [o for o in ops if o.opcode == "ragged_matmul"]
+        v = op.inputs[2]
+        if v.base.uid not in self._group_sizes:
+            self._group_sizes[v.base.uid] = jnp.copy(
+                _read(buffers[v.base.uid], v))
+
+    def emit_group_rows(self) -> None:
+        """Read back the group sizes kept since the last call and emit one
+        ``moe.rows`` span: ``rows`` (all groups' rows, each group-size array
+        counted once: the rows routed to the held experts, over all
+        layers), ``max_rows`` (the largest group), ``groups`` (groups read)
+        and ``arrays`` (group-size arrays read).  Nothing is kept, so
+        nothing is read, while no tracer records."""
+        if not self._group_sizes:
+            return
+        kept, self._group_sizes = self._group_sizes, {}
+        with trace.span("moe.rows") as sp:
+            sizes = [np.asarray(b) for b in kept.values()]
+            sp.set(rows=int(sum(float(s.sum()) for s in sizes)),
+                   max_rows=int(max(float(s.max()) for s in sizes)),
+                   groups=int(sum(s.size for s in sizes)),
+                   arrays=len(sizes))
 
     def run_loop(self, loop_plan, state: Sequence, invariants: Sequence,
                  salts, n: int) -> Tuple:

@@ -28,8 +28,11 @@ from .ir import COMM_OPS, ELEMENTWISE, REDUCTIONS, Op, View
 # one table element through the index operand).
 FUSIBLE_OPCODES = (set(ELEMENTWISE) | REDUCTIONS
                    | {"random", "range", "gather"} | COMM_OPS)
-# opcodes that never share a block with a non-system op (irregular access).
-OPAQUE_OPCODES = {"matmul"}
+# opcodes that never share a block with a non-system op (irregular access):
+# products, and the routing pair of a sparse-expert layer (a sort's output
+# element depends on its whole row; a grouped product's rows on runtime
+# group sizes).
+OPAQUE_OPCODES = {"matmul", "argsort", "ragged_matmul"}
 
 
 def data_parallel(op: Op) -> bool:

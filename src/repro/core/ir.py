@@ -147,7 +147,8 @@ ELEMENTWISE = {
     "reciprocal": 1, "mod": 2, "floor": 1, "sigmoid": 1,
 }
 REDUCTIONS = {"reduce_sum", "reduce_max", "reduce_min", "reduce_prod"}
-SPECIAL = {"random", "range", "matmul", "gather", "del", "sync", "free"}
+SPECIAL = {"random", "range", "matmul", "gather", "argsort", "ragged_matmul",
+           "del", "sync", "free"}
 # Explicit communication ops (distributed fusion, core/dist).  Value
 # semantics: identity copy into a fresh base with a different ShardSpec —
 # only the *placement* changes.  The resharding pass injects them wherever

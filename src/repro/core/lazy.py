@@ -320,6 +320,7 @@ class Runtime:
             from .executor import _read
             out = np.asarray(_read(buf, view))
             sp.set(bytes=out.nbytes)
+        self.executor.emit_group_rows()
         return out
 
     def adopt(self, arr: np.ndarray) -> "LazyArray":
@@ -786,6 +787,39 @@ def take(a: LazyArray, idx, axis: int = 0) -> LazyArray:
     shape = a.shape[:axis] + idx.shape + a.shape[axis + 1:]
     out = _alloc(a.rt, shape, a.dtype)
     a.rt.record(Op("gather", out.view, (a.view, idx.view), axis=axis))
+    return out
+
+
+def argsort(a: LazyArray, axis: int = -1) -> LazyArray:
+    """Stable ascending sort order of ``a`` along ``axis`` (``jnp.argsort``
+    with ``stable=True``: equal keys keep their index order).  The indices
+    are float-carried in ``a``'s dtype, as ``take``'s are, which is exact
+    below 2**24 in float32.  An opaque op (``fusion.OPAQUE_OPCODES``)."""
+    if axis < 0:
+        axis += a.ndim
+    assert 0 <= axis < a.ndim, f"axis {axis} out of range for ndim {a.ndim}"
+    assert a.shape[axis] <= 2 ** (np.finfo(a.dtype).nmant + 1), \
+        f"{a.shape[axis]} indices are not exact in {a.dtype}"
+    out = _alloc(a.rt, a.shape, a.dtype)
+    a.rt.record(Op("argsort", out.view, (a.view,), axis=axis))
+    return out
+
+
+def ragged_matmul(x: LazyArray, w: LazyArray,
+                  group_sizes: LazyArray) -> LazyArray:
+    """Grouped product of ``x`` ``(m, d)`` with ``w`` ``(g, d, f)``: the
+    first ``group_sizes[0]`` rows of ``x`` times ``w[0]``, the next
+    ``group_sizes[1]`` times ``w[1]``, and so on (``jax.lax.ragged_dot``).
+    Rows past ``sum(group_sizes)`` come out zero.  ``group_sizes`` is a
+    runtime operand (float-carried counts), never part of a plan's key, so
+    one executable serves every routing.  An opaque op."""
+    m, d = x.shape
+    g, d2, f = w.shape
+    assert d == d2 and group_sizes.shape == (g,), \
+        (x.shape, w.shape, group_sizes.shape)
+    out = _alloc(x.rt, (m, f), x.dtype)
+    x.rt.record(Op("ragged_matmul", out.view,
+                   (x.view, w.view, group_sizes.view)))
     return out
 
 

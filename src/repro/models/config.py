@@ -10,6 +10,7 @@ and HLO size).
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass, field
 from typing import List, Optional, Tuple
 
@@ -21,10 +22,57 @@ class MoEConfig:
     n_experts: int
     top_k: int
     d_expert: int                 # per-expert FFN hidden dim
+    # capacity and aux losses: the direct stack's GShard layer only (the
+    # lazy runtime's expert layer is dropless and serves, it never trains)
     capacity_factor: float = 1.25
     router_z_coef: float = 1e-3
     load_balance_coef: float = 1e-2
     n_shared_experts: int = 0
+    scoring: str = "softmax"      # router scores over all experts
+    topk_method: str = "greedy"   # top_k of the scores, lower index on ties
+    norm_topk_prob: bool = True   # renormalise the top_k gates to sum 1
+    routed_scaling_factor: float = 1.0
+    #: ``[start, stop)`` of the experts this device holds (expert
+    #: parallelism); None holds them all.  The router still scores all
+    #: ``n_experts``; only the held ones are computed.
+    held_experts: Optional[Tuple[int, int]] = None
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        return self.held_experts or (0, self.n_experts)
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1):
+    keys and values come from one per-token latent of ``kv_lora_rank``
+    plus a rotary key of ``qk_rope_head_dim`` shared by every head."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    q_lora_rank: Optional[int] = None
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (``rope_scaling`` of type ``yarn``)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-magnitude factor ``0.1 * mscale * ln(factor) + 1``
+    (1 for no scaling)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
 @dataclass(frozen=True)
@@ -59,12 +107,15 @@ class ModelConfig:
     sliding_window: Optional[int] = None    # gemma2 local layers (4096)
     local_global_period: Optional[int] = None  # gemma2: 2 → alternate L,G
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnConfig] = None
+    mla: Optional[MLAConfig] = None         # deepseek-v2 latent attention
     tie_embeddings: bool = False
     act: str = "silu"                       # silu (swiglu) | gelu (geglu/mlp)
     norm_plus_one: bool = False             # gemma-style (1+g) scale
     # MoE / SSM / hybrid
     moe: Optional[MoEConfig] = None
     moe_period: int = 1                     # apply MoE every k-th layer
+    first_k_dense: int = 0                  # leading layers with a dense FFN
     mamba: Optional[MambaConfig] = None
     attn_period: Optional[int] = None       # jamba: attention every k layers
     attn_offset: int = 0                    # jamba: first attn layer index
@@ -103,8 +154,8 @@ class ModelConfig:
                          else "attn")
             else:
                 mixer = "attn"
-            if self.moe is not None and (l % self.moe_period ==
-                                         (self.moe_period - 1)):
+            if (self.moe is not None and l >= self.first_k_dense
+                    and l % self.moe_period == (self.moe_period - 1)):
                 ffn = "moe"
             else:
                 ffn = "mlp"

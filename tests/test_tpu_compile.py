@@ -95,8 +95,9 @@ def _blocks(tape, backends):
     return out
 
 
-def _compile(one_chip, backend, ops, plan=None):
-    """AOT-compile one block's executable for the described chip."""
+def _compile(one_chip, backend, ops, plan=None, custom=True):
+    """AOT-compile one block's executable for the described chip; with
+    ``custom``, it must hold a kernel (a ``tpu_custom_call``)."""
     if plan is None:
         from repro.core.scheduler import plan_blocks
         plan = plan_blocks(ops, [list(range(len(ops)))])[0]
@@ -110,7 +111,7 @@ def _compile(one_chip, backend, ops, plan=None):
             for u in plan.inputs]
     args.append(jax.ShapeDtypeStruct((n_rand,), jnp.int32, sharding=one_chip))
     compiled = jax.jit(fn, out_shardings=one_chip).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert not custom or "tpu_custom_call" in compiled.as_text()
     return compiled
 
 
@@ -221,6 +222,31 @@ def test_vmem_budget_edge(one_chip):
     assert rowblock_lower_reason(fits) is None
     _compile(one_chip, "flash_attention", fits)
     assert rowblock_lower_reason(softmax_rows(64, 151936)) == "vmem"
+
+
+def test_routing_blocks_at_deepseek_v2_lite_widths(one_chip):
+    """The expert layer's opaque blocks of a 2048-token prefill: the top-6
+    sort of 2048 x 64 router scores, the sort of the 12288 assignments, and
+    the grouped product of their rows with 16 held experts of 2048 x 1408,
+    declined by every kernel claimant and compiled by XLA (the product to
+    the TPU's ``ragged-dot`` kernel)."""
+    n, e, a, d, g, f = 2048, 64, 12288, 2048, 16, 1408
+    s, o = BaseArray(n * e, F32), BaseArray(n * e, F32)
+    k, p = BaseArray(a, F32), BaseArray(a, F32)
+    x, w = BaseArray(a * d, F32), BaseArray(g * d * f, F32)
+    sizes, y = BaseArray(g, F32), BaseArray(a * f, F32)
+    blocks = [
+        [Op("argsort", _v(o, (n, e)), (_v(s, (n, e)),), axis=1)],
+        [Op("argsort", _v(p, (a,)), (_v(k, (a,)),), axis=0)],
+        [Op("ragged_matmul", _v(y, (a, f)),
+            (_v(x, (a, d)), _v(w, (g, d, f)), _v(sizes, (g,))))]]
+    for ops in blocks:
+        for name in LM_STACK[:-1]:
+            assert get_backend(name).claims(ops, None, COMPILED) is not None
+    with jax.default_matmul_precision("highest"):
+        texts = [_compile(one_chip, "xla", ops, custom=False).as_text()
+                 for ops in blocks]
+    assert "ragged-dot" in texts[2] and "tpu_custom_call" in texts[2]
 
 
 # ---------------------------------------------------------------------------
