@@ -29,11 +29,11 @@ def build_batch_fn(tape: Sequence, plans: Sequence,
     """Compose a planned flush into a vmapped multi-request executable.
 
     Returns ``(fn, n_rand)`` where ``fn(inputs, salts) -> outputs`` maps a
-    tuple of ``(B, size)`` stacked tape-input buffers and a ``(B, n_rand)``
-    int32 salt matrix to a tuple of ``(B, size)`` stacked tape-output
-    buffers (canonical ``tape_io`` order on all three).  ``salts`` always
-    carries the batch axis — even with ``n_rand == 0`` — so ``vmap`` has a
-    mapped operand on tapes with no inputs.
+    tuple of ``(B, *base shape)`` stacked tape-input buffers and a ``(B,
+    n_rand)`` int32 salt matrix to a tuple of ``(B, *base shape)`` stacked
+    tape-output buffers (canonical ``tape_io`` order on all three).
+    ``salts`` always carries the batch axis — even with ``n_rand == 0`` —
+    so ``vmap`` has a mapped operand on tapes with no inputs.
 
     Blocks build on the backend their ``BlockPlan.lowering`` decision
     names; a builder failure raises ``BackendBuildError``, as in the

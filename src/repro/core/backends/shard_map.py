@@ -4,9 +4,10 @@ collectives (DESIGN.md §12, §14).
 Extracted from the former ``DistBlockExecutor`` subclass so the distributed
 path is a *peer* backend the lower stage selects per block: blocks that
 touch sharded bases lower through ``jax.shard_map`` over a 1-D device mesh
-— sharded bases enter as per-device chunks (``P(axis)`` on the flat buffer;
-dim-0 block sharding keeps chunks contiguous), replicated bases enter
-whole, and COMM ops become real collectives (``all_gather`` for
+— sharded bases enter as per-device chunks (``P(axis)`` on the buffer's
+leading dimension; dim-0 block sharding keeps chunks contiguous, and each
+is flattened on entry and leaves in its base's shape), replicated bases
+enter whole, and COMM ops become real collectives (``all_gather`` for
 allgather/ppermute resharding, shard-local slices for placement casts).
 Identical COMM ops inside one block execute as ONE collective — the
 backend realizes the elision the ``comm`` cost model priced.
@@ -25,6 +26,7 @@ package init imports the executor back).
 
 from __future__ import annotations
 
+import math
 from typing import Dict, Optional, Sequence, Tuple
 
 from .base import LoweringBackend, LoweringContext
@@ -64,7 +66,8 @@ def shard_specs(work: Sequence, n_dev: int) -> Tuple[Optional[Dict], Optional[st
             if s is not None:
                 if (s.sharded_dim != 0 or not s.divides()
                         or s.n_shards != n_dev
-                        or v.base.size % n_dev != 0):
+                        or not v.base.shape
+                        or v.base.shape[0] % n_dev != 0):
                     return None, "placement"
                 any_sharded = True
             specs[v.base.uid] = s
@@ -113,23 +116,32 @@ class ShardMapBackend(LoweringBackend):
         inputs, outputs, _ = block_io(ops)
         meta = _base_meta(work)
         n_dev, axis = ctx.n_dev, ctx.axis
-        chunk = {u: size // n_dev for u, (size, _) in meta.items()}
+        chunk = {u: math.prod(shape) // n_dev
+                 for u, (shape, _) in meta.items()}
+
+        def local_shape(u):
+            """A device's part of base ``u``'s buffer (its shape, or its
+            leading dimension's chunk when sharded)."""
+            shape = meta[u][0]
+            if specs.get(u) is None:
+                return shape
+            return (shape[0] // n_dev,) + tuple(shape[1:])
 
         def shard_of(val, u):
             idx = jax.lax.axis_index(axis)
             return jax.lax.dynamic_slice_in_dim(val, idx * chunk[u], chunk[u])
 
         def pershard(*bufs):
-            env: Dict[int, jnp.ndarray] = {u: b for u, b in zip(inputs, bufs)}
-            for u, (size, dt) in meta.items():
+            env: Dict[int, jnp.ndarray] = {u: b.reshape(-1)
+                                           for u, b in zip(inputs, bufs)}
+            for u, (shape, dt) in meta.items():
                 if u not in env:
-                    local = chunk[u] if specs.get(u) is not None else size
-                    env[u] = jnp.zeros((local,), dt)
+                    env[u] = jnp.zeros((math.prod(local_shape(u)),), dt)
             issued: Dict[Tuple, jnp.ndarray] = {}
             for op in work:
                 oc = op.opcode
                 ou = op.out.base.uid
-                size, dt = meta[ou]
+                dt = meta[ou][1]
                 if oc in COMM_OPS:
                     key = _comm_key(op)
                     val = issued.get(key)
@@ -161,9 +173,9 @@ class ShardMapBackend(LoweringBackend):
                     val = _BINARY[oc](*ins)
                 else:
                     val = jnp.where(*ins)
-                local = chunk[ou] if sharded_out else size
+                local = math.prod(local_shape(ou))
                 env[ou] = jnp.broadcast_to(jnp.asarray(val, dt), (local,))
-            return tuple(env[u] for u in outputs)
+            return tuple(env[u].reshape(local_shape(u)) for u in outputs)
 
         pspec = lambda u: P(axis) if specs.get(u) is not None else P()  # noqa: E731
         mapped = shard_map(pershard, mesh=ctx.mesh,

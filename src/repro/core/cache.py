@@ -47,7 +47,8 @@ def op_struct(op: Op) -> Tuple[Tuple, Tuple[int, ...]]:
     ``(template, base_uids)`` pair.
 
     ``template`` captures everything structural about the op — opcode, axis,
-    per-view geometry (size/dtype/offset/shape/strides), literal operands,
+    per-view geometry (size/dtype/offset/shape/strides and the base's
+    shape), literal operands,
     and *local* indices into ``base_uids`` wherever a base is referenced —
     while ``base_uids`` is the ordered tuple of base uids those indices
     name (views in program order first, then any new/del/sync-only bases in
@@ -65,8 +66,10 @@ def op_struct(op: Op) -> Tuple[Tuple, Tuple[int, ...]]:
         return local.setdefault(uid, len(local))
 
     def vk(v: View) -> Tuple:
+        # the base's shape is its buffer's (ir.BaseArray): an executable
+        # traced for one buffer shape is never replayed on another
         return (li(v.base.uid), v.base.size, _dt(v.base.dtype), v.offset,
-                v.shape, v.strides)
+                v.shape, v.strides, v.base.shape)
 
     ins = tuple(vk(v) if isinstance(v, View) else ("lit", float(v))
                 for v in op.inputs)
@@ -311,7 +314,7 @@ class TapeMatcher:
                     b = v.base
                     tb = tv.base
                     if (v.offset != tv.offset or v.shape != tv.shape
-                            or v.strides != tv.strides or b.size != tb.size
+                            or v.strides != tv.strides or b.shape != tb.shape
                             or b.dtype != tb.dtype):
                         return None
                     uapp(b.uid)
@@ -324,7 +327,7 @@ class TapeMatcher:
                 b = v.base
                 tb = tout.base
                 if (v.offset != tout.offset or v.shape != tout.shape
-                        or v.strides != tout.strides or b.size != tb.size
+                        or v.strides != tout.strides or b.shape != tb.shape
                         or b.dtype != tb.dtype):
                     return None
                 uapp(b.uid)

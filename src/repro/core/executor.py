@@ -154,10 +154,12 @@ def _permute_plan(v: View, write: bool = False) -> Optional[Tuple]:
 
 def _view_lowering(v: View, write: bool = False) -> Tuple[str, object]:
     """How ``_read`` (``write=False``) or ``_write`` lowers a view,
-    ``"whole" | "slice" | "permute" | "gather"``, and the static plan of
-    that lowering, tried in this order:
+    ``"stored" | "whole" | "slice" | "permute" | "gather"``, and the static
+    plan of that lowering, tried in this order:
 
-    * ``whole``: the contiguous base, a reshape;
+    * ``stored``: the contiguous base in its buffer's own shape
+      (``BaseArray.shape``), the buffer as it is;
+    * ``whole``: the contiguous base in another shape, a reshape;
     * ``slice``: ``_slice_plan``, a reshape and one static slice;
     * ``permute``: ``_permute_plan``, that slice of the view's axes
       reordered by stride, then a transpose back and a broadcast over the
@@ -166,7 +168,7 @@ def _view_lowering(v: View, write: bool = False) -> Tuple[str, object]:
       reordering of a nested row-major slice describes.
     """
     if v.offset == 0 and v.size == v.base.size and v.is_contiguous():
-        return "whole", None
+        return ("stored" if v.shape == v.base.shape else "whole"), None
     plan = _slice_plan(v)
     if plan is not None:
         return "slice", plan
@@ -183,7 +185,12 @@ def _sliced(buf, plan):
 
 
 def _read(buf, v: View):
+    """The elements of ``v`` in its shape, from ``buf``, a store buffer of
+    ``v.base.shape``; the plans index the base's flat row-major order,
+    which a reshape of any shape of the base's size gives."""
     how, plan = _view_lowering(v)
+    if how == "stored":
+        return buf
     if how == "whole":
         return buf.reshape(v.shape)
     if how == "slice":
@@ -196,14 +203,16 @@ def _read(buf, v: View):
         sub = sub.reshape(tuple(s if i in kept else 1
                                 for i, s in enumerate(v.shape)))
         return jnp.broadcast_to(sub, v.shape)
-    return buf[_view_index(v)].reshape(v.shape)
+    return buf.reshape(-1)[_view_index(v)].reshape(v.shape)
 
 
 def _write(buf, v: View, val):
+    """``buf`` with ``val`` written through ``v``, returned in
+    ``v.base.shape`` (the store invariant, ``ir.BaseArray``)."""
     val = jnp.broadcast_to(jnp.asarray(val, buf.dtype), v.shape)
     how, plan = _view_lowering(v, write=True)
-    if how == "whole":
-        return val.reshape(-1)
+    if how in ("stored", "whole"):
+        return val.reshape(v.base.shape)
     if how == "permute":
         perm, plan = plan
         val = jnp.transpose(val, perm)
@@ -211,8 +220,9 @@ def _write(buf, v: View, val):
         dims, starts, sizes = plan
         window = tuple(slice(a, a + n) for a, n in zip(starts, sizes))
         out = buf.reshape(dims).at[window].set(val.reshape(sizes))
-        return out.reshape(-1)
-    return buf.at[_view_index(v)].set(val.reshape(-1))
+        return out.reshape(v.base.shape)
+    out = buf.reshape(-1).at[_view_index(v)].set(val.reshape(-1))
+    return out.reshape(v.base.shape)
 
 
 def block_dead_bases(ops: Sequence[Op]) -> set:
@@ -264,11 +274,13 @@ def block_io(ops: Sequence[Op]) -> Tuple[List[int], List[int], List[int]]:
     return inputs, outputs, contracted
 
 
-def _base_meta(ops: Sequence[Op]) -> Dict[int, Tuple[int, np.dtype]]:
-    meta: Dict[int, Tuple[int, np.dtype]] = {}
+def _base_meta(ops: Sequence[Op]) -> Dict[int, Tuple[Tuple[int, ...],
+                                                   np.dtype]]:
+    """Base uid -> ``(shape, dtype)`` of every base the ops touch."""
+    meta: Dict[int, Tuple[Tuple[int, ...], np.dtype]] = {}
     for op in ops:
         for v in (*op.in_views(), *op.out_views()):
-            meta[v.base.uid] = (v.base.size, v.base.dtype)
+            meta[v.base.uid] = (v.base.shape, v.base.dtype)
     return meta
 
 
@@ -288,16 +300,25 @@ def make_block_fn(ops: Sequence[Op], seed: int = 0):
     output_bufs`` is pure and jittable.  All view indices are static
     constants, so XLA sees one straight-line fused program per block — the
     fusion boundary is exactly what WSP chose.  ``fn.view_lowerings``
-    counts the block's view reads and writes by ``_view_lowering``.
+    counts the block's view reads and writes by ``_view_lowering``;
+    ``fn.stored_bytes`` sums the bytes of the distinct views of the block's
+    input bases that it reads as ``"stored"``, its buffers taken as they
+    are.
     """
     work = [op for op in ops if not op.is_system()]
     inputs, outputs, contracted = block_io(ops)   # DEL/SYNC drive contraction
     meta = _base_meta(work)
-    lowerings = dict.fromkeys(("whole", "slice", "permute", "gather"), 0)
+    lowerings = dict.fromkeys(("stored", "whole", "slice", "permute",
+                               "gather"), 0)
+    stored = {}
+    input_set = set(inputs)
     for op in work:
         for v in op.inputs:
             if isinstance(v, View):
-                lowerings[_view_lowering(v)[0]] += 1
+                how = _view_lowering(v)[0]
+                lowerings[how] += 1
+                if how == "stored" and v.base.uid in input_set:
+                    stored[v.base.uid] = v.nbytes
         if op.out is not None:
             lowerings[_view_lowering(op.out, write=True)[0]] += 1
 
@@ -307,8 +328,8 @@ def make_block_fn(ops: Sequence[Op], seed: int = 0):
         n_rand = 0
         for u in meta:
             if u not in env:
-                size, dtype = meta[u]
-                env[u] = jnp.zeros((size,), dtype=dtype)
+                shape, dtype = meta[u]
+                env[u] = jnp.zeros(shape, dtype=dtype)
         for op in work:
             ins = [(_read(env[v.base.uid], v) if isinstance(v, View) else v)
                    for v in op.inputs]
@@ -361,6 +382,7 @@ def make_block_fn(ops: Sequence[Op], seed: int = 0):
         return tuple(env[u] for u in outputs)
 
     fn.view_lowerings = lowerings
+    fn.stored_bytes = sum(stored.values())
     return fn, inputs, outputs
 
 
@@ -616,10 +638,11 @@ class BlockExecutor:
         """Look up (or build) the jitted executable for one decided plan.
         Returns ``(fn, donates, name, views, warm)``; ``warm`` is True on
         a cache hit (the profiler times only warm dispatches — cold ones
-        include trace+compile time).  ``views`` is ``(permutes, gathers)``,
-        the block's view reads and writes that its ``xla`` lowering
-        (``make_block_fn``) takes through a transpose or broadcast and
-        through an index gather; 0 and 0 on every other backend.
+        include trace+compile time).  ``views`` is ``(permutes, gathers,
+        stored_bytes)``: the block's view reads and writes that its ``xla``
+        lowering (``make_block_fn``) takes through a transpose or broadcast
+        and through an index gather, and the bytes of the input buffers it
+        reads as they are stored; 0, 0 and 0 on every other backend.
         ``name`` is the executable's stable name,
         ``repro_block_<backend>_<signature digest>``, which XLA's module
         takes (``jit_<name>``) so that a device trace names a block the
@@ -643,7 +666,8 @@ class BlockExecutor:
             fn = build_block(decision.backend, ops, plan, ctx)
             lowerings = getattr(fn, "view_lowerings", {})
             views = (lowerings.get("permute", 0),
-                     lowerings.get("gather", 0))
+                     lowerings.get("gather", 0),
+                     getattr(fn, "stored_bytes", 0))
             donate = (plan.donatable if self.jit and be.donates
                       and self.donation_enabled() else ())
             name = (f"repro_block_{decision.backend}_"
@@ -685,12 +709,13 @@ class BlockExecutor:
         """Dispatch a planned flush (stage 6) against the buffer store.
 
         ``schedule`` is the :class:`repro.core.scheduler.Schedule` produced
-        by ``Scheduler.plan``; ``buffers`` maps base uid -> flat device
-        buffer and is updated in place with each block's outputs.  Per
-        block: take the plan's lowering decision (or decide now), look up
-        (or compile) the executable under ``(backend, signature)``, feed
-        the external input buffers plus the RNG salts, then honor SYNC
-        (snapshot into ``sync_store``) and DEL (free) in Bohrium order.
+        by ``Scheduler.plan``; ``buffers`` maps base uid -> device buffer
+        of the base's shape and is updated in place with each block's
+        outputs.  Per block: take the plan's lowering decision (or decide
+        now), look up (or compile) the executable under ``(backend,
+        signature)``, feed the external input buffers plus the RNG salts,
+        then honor SYNC (snapshot into ``sync_store``) and DEL (free) in
+        Bohrium order.
         Dispatch is async — nothing here blocks on device results."""
         from .backends import get_backend
         use_compile_cache()
@@ -735,7 +760,8 @@ class BlockExecutor:
                     with trace.span("block", backend=decision.backend,
                                     n_ops=len(plan.op_indices), name=name,
                                     cold=not warm, permutes=views[0],
-                                    gathers=views[1], **extra):
+                                    gathers=views[1],
+                                    stored_bytes=views[2], **extra):
                         if timing:
                             jax.block_until_ready(in_bufs)  # drain queued
                             t0 = time.perf_counter()   # work so the clock
@@ -848,9 +874,9 @@ class BlockExecutor:
         ``schedule`` is the lead request's planned flush (the structural
         template), ``tape_inputs``/``tape_outputs`` its tape-level io in
         canonical ``cache.tape_io`` order, ``in_cols`` one column per input
-        position (each a length-B list of flat buffers, request order) and
+        position (each a length-B list of store buffers, request order) and
         ``salt_rows`` one row per request of that request's ``random``-op
-        salts (schedule work-block order).  Returns one ``(B, size)``
+        salts (schedule work-block order).  Returns one ``(B, *shape)``
         stacked buffer per output position; the caller scatters row ``r``
         back into request ``r``'s buffer store.
 

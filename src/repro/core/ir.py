@@ -1,7 +1,8 @@
 """Array-bytecode IR — the Bohrium-style instruction stream (paper §III-A).
 
-A *base* array is a contiguous 1-D buffer; a *view* observes part of a base
-with (offset, shape, strides) in elements.  Array operations read/write views;
+A *base* array is a contiguous buffer of ``size`` elements in row-major
+order; a *view* observes part of a base with (offset, shape, strides) in
+elements of that order.  Array operations read/write views;
 ``DEL`` destroys a base, ``SYNC`` materializes it to the host language.  This
 module defines the IR only — recording happens in ``repro.core.lazy`` and
 partitioning in ``repro.core.fusion``.
@@ -10,6 +11,7 @@ partitioning in ``repro.core.fusion``.
 from __future__ import annotations
 
 import itertools
+import math
 from dataclasses import dataclass
 from math import gcd
 from typing import Optional, Sequence, Tuple
@@ -22,15 +24,32 @@ _op_counter = itertools.count()
 
 @dataclass(eq=False)
 class BaseArray:
-    """A contiguous 1-D backing buffer (paper: "base array")."""
+    """A contiguous backing buffer (paper: "base array").
+
+    Views address it by flat row-major element index, whatever its
+    ``shape``.  ``shape`` is the shape of its device buffer: ``(size,)``
+    unless the host handed the runtime an array of another shape
+    (``Runtime.adopt``).  Store invariant: a buffer in the runtime's store
+    always has its base's ``shape`` — every path that makes or writes one
+    (block functions, kernels, the loop and batch bodies, the shard_map
+    backend) returns it so, and a whole view of that shape is read as it
+    is, with no relayout (``executor._view_lowering``'s ``"stored"``)."""
 
     size: int                      # number of elements
     dtype: np.dtype
     name: str = ""
+    shape: Optional[Tuple[int, ...]] = None
 
     def __post_init__(self):
         self.uid: int = next(_base_counter)
         self.dtype = np.dtype(self.dtype)
+        if self.shape is None:
+            self.shape = (self.size,)
+        else:
+            self.shape = tuple(int(s) for s in self.shape)
+            if math.prod(self.shape) != self.size:
+                raise ValueError(f"shape {self.shape} does not hold "
+                                 f"{self.size} elements")
         if not self.name:
             self.name = f"b{self.uid}"
         # Optional distributed placement (repro.core.dist.ShardSpec).  None

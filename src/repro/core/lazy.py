@@ -324,12 +324,15 @@ class Runtime:
         return out
 
     def adopt(self, arr: np.ndarray) -> "LazyArray":
-        """Bring host data into the runtime (no bytecode recorded)."""
+        """Bring host data into the runtime (no bytecode recorded).  The
+        device buffer keeps ``arr``'s shape, and so does its base: a block
+        that reads the whole array in that shape takes the buffer as it
+        is, with no relayout on the device."""
         with trace.span("adopt", flush=self.flushes) as sp:
             arr = np.ascontiguousarray(arr)
             sp.set(bytes=arr.nbytes)
-            base = BaseArray(arr.size, arr.dtype)
-            self.buffers[base.uid] = jnp.asarray(arr.reshape(-1))
+            base = BaseArray(arr.size, arr.dtype, shape=arr.shape)
+            self.buffers[base.uid] = jnp.asarray(arr)
         return LazyArray(self, View.contiguous(base, arr.shape))
 
     # -- sessions (concurrent serving, DESIGN.md §18) ------------------

@@ -76,7 +76,8 @@ class _Request:
         self.tape = tape
         self.arrs = arrs
         self.out_uids: Tuple[int, ...] = ()
-        #: per-output (size,) buffers from the batched dispatch; None means
+        #: per-output buffers, each of its base's shape, from the batched
+        #: dispatch (a row of ``run_batch``'s stacks); None means
         #: "execute your tape yourself" (group of one / non-batchable plan)
         self.out_bufs = None
         self.error: Optional[BaseException] = None

@@ -176,7 +176,9 @@ class _Plan:
     epilogue: Dict[int, List[Tuple[str, int, Optional[View]]]] = field(default_factory=dict)
     inputs: List[int] = field(default_factory=list)
     outputs: List[int] = field(default_factory=list)
-    base_meta: Dict[int, Tuple[int, np.dtype]] = field(default_factory=dict)
+    #: base uid -> (shape, dtype): outputs leave in the base's shape
+    base_meta: Dict[int, Tuple[Tuple[int, ...], np.dtype]] = field(
+        default_factory=dict)
 
     @property
     def R_pad(self) -> int:
@@ -288,7 +290,7 @@ def _analyze(ops: Sequence[Op]) -> _Plan:
                  inputs=list(inputs), outputs=list(outputs))
     for op in work:
         for v in (*op.in_views(), *op.out_views()):
-            plan.base_meta[v.base.uid] = (v.base.size, v.base.dtype)
+            plan.base_meta[v.base.uid] = (v.base.shape, v.base.dtype)
 
     op_index: Dict[Tuple, int] = {}
     dense_slot: Dict[int, int] = {}             # output base -> shared slot
@@ -736,13 +738,13 @@ def build_block_kernel(ops: Sequence[Op], *, seed: int = 0,
         outs = call(*[_shape_operand(o, store, rvals) for o in p.operands])
         final: Dict[int, jnp.ndarray] = {}
         for u in p.outputs:
-            size, dt = p.base_meta[u]
-            cur = store[u] if u in input_set else jnp.zeros((size,), dt)
+            shape, dt = p.base_meta[u]
+            cur = store[u] if u in input_set else jnp.zeros(shape, dt)
             for wkind, slot, view in p.epilogue.get(u, []):
                 raw = outs[slot].reshape(-1)
                 if wkind == "whole":
                     # reductions accumulate in input dtype; cast once here
-                    cur = raw[:size].astype(dt)
+                    cur = raw[:math.prod(shape)].astype(dt).reshape(shape)
                 else:
                     cur = _write(cur, view, raw[:N].reshape(p.domain))
             final[u] = cur

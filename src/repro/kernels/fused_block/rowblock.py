@@ -79,7 +79,9 @@ class _RowPlan:
     nodes: List[_Node] = field(default_factory=list)
     inputs: List[int] = field(default_factory=list)
     outputs: List[int] = field(default_factory=list)
-    base_meta: Dict[int, Tuple[int, np.dtype]] = field(default_factory=dict)
+    #: base uid -> (shape, dtype): outputs leave in the base's shape
+    base_meta: Dict[int, Tuple[Tuple[int, ...], np.dtype]] = field(
+        default_factory=dict)
 
     @property
     def R_pad(self) -> int:
@@ -123,7 +125,7 @@ def _analyze(ops: Sequence[Op]) -> _RowPlan:
                     inputs=list(inputs), outputs=list(outputs))
     for op in work:
         for v in (*op.in_views(), *op.out_views()):
-            plan.base_meta[v.base.uid] = (v.base.size, v.base.dtype)
+            plan.base_meta[v.base.uid] = (v.base.shape, v.base.dtype)
 
     op_index: Dict[Tuple, int] = {}
     dense_slot: Dict[int, int] = {}
@@ -331,8 +333,9 @@ def build_rowblock_kernel(ops: Sequence[Op], *, seed: int = 0,
         outs = call(*[_shape_operand(o, store) for o in p.operands])
         final: Dict[int, jnp.ndarray] = {}
         for slot, (kind, _, u) in enumerate(p.slots):
-            size, dt = p.base_meta[u]
-            final[u] = outs[slot].reshape(-1)[:size].astype(dt)
+            shape, dt = p.base_meta[u]
+            final[u] = (outs[slot].reshape(-1)[:math.prod(shape)].astype(dt)
+                        .reshape(shape))
         return tuple(final[u] for u in p.outputs)
 
     return fn, list(p.inputs), list(p.outputs)

@@ -259,6 +259,16 @@ class LazyTransformer:
         self._rope_cache[key] = (cos, sin)
         return cos, sin
 
+    def _zero_cache(self, shape: Tuple[int, ...]) -> LazyArray:
+        """A zeroed KV cache of ``shape``, handed to the runtime flat and
+        viewed in ``shape``.  Prefill writes it through views and nothing
+        reads it whole, so it gains nothing from a buffer of its own shape,
+        while copying an N-D host array to a TPU holds the host longer: on
+        a v5e, dsllm7b's 8 caches of a 2048-token prefill held it 31.5 ms
+        in ``adopt`` in their own shape, against 3.6 ms flat."""
+        return self.rt.adopt(
+            np.zeros(math.prod(shape), np.float32)).reshape(shape)
+
     def _causal_mask(self, s: int) -> LazyArray:
         key = ("causal", s)
         if key not in self._mask_cache:
@@ -514,17 +524,14 @@ class LazyTransformer:
             for lp in self.layers:
                 if self.cfg.mla is not None:
                     a = self.cfg.mla
-                    c = self.rt.adopt(np.zeros(
-                        (b, max_seq, a.kv_lora_rank + a.qk_rope_head_dim),
-                        np.float32))
+                    c = self._zero_cache(
+                        (b, max_seq, a.kv_lora_rank + a.qk_rope_head_dim))
                     x = self._layer(lp, x, lambda lp_, h, c=c:
                                     self._mla_prefill(lp_, h, c))
                     self.caches.append(c)
                     continue
-                ck = self.rt.adopt(
-                    np.zeros((b, max_seq, kvh, hd), np.float32))
-                cv = self.rt.adopt(
-                    np.zeros((b, max_seq, kvh, hd), np.float32))
+                ck = self._zero_cache((b, max_seq, kvh, hd))
+                cv = self._zero_cache((b, max_seq, kvh, hd))
                 x = self._layer(
                     lp, x, lambda lp_, h, ck=ck, cv=cv:
                     self._attention_prefill(lp_, h, ck, cv))

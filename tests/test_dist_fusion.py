@@ -397,6 +397,46 @@ def test_dist_executor_cache_key_sees_placement():
     assert k1 != k2
 
 
+def test_eight_device_mesh_adopted_grids_subprocess():
+    """Adopted 2-D grids, sharded by rows, on a real 8-device mesh: a grid
+    whose rows divide over the mesh runs through ``shard_map`` and comes
+    back in its own shape; one whose rows do not declines with
+    ``placement``.  Both give NumPy's answer."""
+    code = textwrap.dedent("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        import numpy as np
+        from repro.core import dist
+        from repro.core import lazy as bh
+        from repro.core.dist import host_mesh
+        from repro.core.lazy import fresh_runtime
+        for shape, sharded in (((16, 6), True), ((4, 16), False)):
+            g = np.arange(np.prod(shape), dtype=np.float64).reshape(shape)
+            with fresh_runtime(cost_model="comm", mesh=host_mesh(8)) as rt:
+                x = bh.asarray(g)
+                dist.shard(x, n=8)
+                y = x * 2.0 + 1.0
+                z = y + x
+                got = z.numpy()
+                buf = rt.buffers[y.view.base.uid]
+                stats = dict(rt.executor.stats)
+            assert np.array_equal(got, g * 3.0 + 1.0), shape
+            assert (stats["shard_map_blocks"] > 0) == sharded, stats
+            assert buf.shape == (g.size,), buf.shape
+            assert rt.buffers[x.view.base.uid].shape == shape
+        print("OK")
+    """)
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (os.path.join(os.path.dirname(__file__), "..", "src"),
+                    env.get("PYTHONPATH", "")) if p)
+    res = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env)
+    assert res.returncode == 0, res.stderr
+    assert "OK" in res.stdout
+
+
 def test_eight_device_mesh_subprocess():
     """Always exercise a real 8-device mesh (mirrors the CI dist job)."""
     code = textwrap.dedent("""

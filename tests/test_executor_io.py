@@ -10,7 +10,11 @@ slice fast path (ISSUE 2 satellites):
   exact read/write equivalence against NumPy's own striding;
 * ``_permute_plan`` lowers transposed and broadcast views to that slice of
   the view's axes reordered by stride, then a transpose and a broadcast;
-  only reversed and overlapping views still read through an index gather.
+  only reversed and overlapping views still read through an index gather;
+* every lowering reads and writes a store buffer of any base shape by the
+  base's flat row-major order, a whole view in the buffer's own shape is
+  read as it is (``"stored"``, no reshape), and a write leaves the buffer
+  in its base's shape.
 """
 
 import itertools
@@ -21,7 +25,7 @@ import pytest
 
 from repro.core.executor import (_read, _slice_plan, _view_index,
                                  _view_lowering, _write, block_dead_bases,
-                                 block_io)
+                                 block_io, make_block_fn)
 from repro.core.ir import BaseArray, Op, View
 
 
@@ -191,7 +195,7 @@ def test_slice_plan_read_write_match_numpy(no_gather, size, off, shape,
                                            strides):
     v = View(_base(size), off, shape, strides)
     assert _slice_plan(v) is not None
-    assert _view_lowering(v)[0] in ("whole", "slice")
+    assert _view_lowering(v)[0] in ("stored", "whole", "slice")
     assert _view_lowering(v, write=True)[0] == _view_lowering(v)[0]
     _check_read(v, size)
     _check_write(v, size)
@@ -260,6 +264,126 @@ def test_fast_path_emits_no_gather_constants(monkeypatch):
     _read(buf, View(b, 0, (6, 6), (1, 6)))      # a transpose permutes
     with pytest.raises(AssertionError):
         _read(buf, View(b, 0, (4, 6), (3, 1)))  # overlapping rows gather
+
+
+# ---------------------------------------------------------------------------
+# store buffers of any base shape (ir.BaseArray.shape)
+# ---------------------------------------------------------------------------
+
+#: 24-element bases: flat, 2-D, 3-D, and 3-D with a size-1 axis
+BASE_SHAPES = [(24,), (4, 6), (2, 3, 4), (2, 1, 12)]
+KINDS = ["stored", "whole", "slice", "permute", "gather"]
+NO_SALTS = jnp.zeros((0,), jnp.int32)
+
+
+def _view_of_kind(b, kind):
+    """A view of the 24-element base ``b`` that ``_view_lowering`` lowers
+    as ``kind``, for reads and writes alike."""
+    if kind == "stored":
+        return View.contiguous(b, b.shape)
+    if kind == "whole":
+        return View.contiguous(b, (4, 6) if len(b.shape) == 1 else (24,))
+    if kind == "slice":
+        return View(b, 2, (3, 4), (6, 1))      # a window of (4, 6) rows
+    if kind == "permute":
+        return View(b, 0, (6, 4), (1, 6))      # (4, 6) transposed
+    return View(b, 23, (24,), (-1,))           # reversed
+
+
+def _flat_read(flat, v):
+    """NumPy's flat semantics of a view: its elements by flat index."""
+    idx = _view_index(v)
+    return (flat if idx is None else flat[idx]).reshape(v.shape)
+
+
+@pytest.mark.parametrize("shape", BASE_SHAPES,
+                         ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("mode", ["read", "write"])
+@pytest.mark.parametrize("kind", KINDS)
+def test_view_lowering_on_a_store_buffer_of_its_base_shape(kind, mode,
+                                                           shape):
+    """Each lowering, read and written, on a buffer of the base's shape:
+    values by NumPy's flat semantics; a block's result, a read-modify-write
+    included, leaves in the base's shape."""
+    b = BaseArray(24, np.dtype(np.float64), shape=shape)
+    v = _view_of_kind(b, kind)
+    assert _view_lowering(v, write=mode == "write")[0] == kind
+    flat = np.arange(24, dtype=np.float64)
+    buf = jnp.asarray(flat.reshape(shape))
+    want_read = _flat_read(flat, v)
+    if mode == "read":
+        np.testing.assert_array_equal(np.asarray(_read(buf, v)), want_read)
+        out = BaseArray(v.size, np.dtype(np.float64))
+        ops = [Op("copy", View.contiguous(out, v.shape), (v,),
+                  new_bases=frozenset({out}))]
+        fn, ins, outs = make_block_fn(ops)
+        assert (ins, outs) == ([b.uid], [out.uid])
+        (got,) = fn(buf, NO_SALTS)
+        assert got.shape == (v.size,)
+        np.testing.assert_array_equal(np.asarray(got).reshape(v.shape),
+                                      want_read)
+        return
+    val = -1.0 - np.arange(v.size, dtype=np.float64).reshape(v.shape)
+    got = _write(buf, v, jnp.asarray(val))
+    want = flat.copy()
+    idx = _view_index(v)
+    want[slice(None) if idx is None else idx] = val.reshape(-1)
+    assert got.shape == shape
+    np.testing.assert_array_equal(np.asarray(got).reshape(-1), want)
+    # the same view read, negated and written back inside one block
+    ops = [Op("neg", v, (v,))]
+    fn, ins, outs = make_block_fn(ops)
+    assert ins == outs == [b.uid]
+    (got,) = fn(buf, NO_SALTS)
+    want = flat.copy()
+    want[slice(None) if idx is None else idx] = -want_read.reshape(-1)
+    assert got.shape == shape
+    np.testing.assert_array_equal(np.asarray(got).reshape(-1), want)
+
+
+def test_whole_adopted_weight_is_read_with_no_reshape():
+    """A block reading a whole adopted 2-D weight takes its buffer as it
+    is: no ``reshape`` of that input in the block's jaxpr, and the block's
+    span counts the weight's bytes as ``stored_bytes``."""
+    import jax
+
+    from repro.core import lazy as bh
+    from repro.core.lazy import fresh_runtime
+    from repro.core.obs import trace
+
+    w_np = np.arange(8 * 16, dtype=np.float32).reshape(8, 16) / 64.0
+    x_np = np.arange(4 * 8, dtype=np.float32).reshape(4, 8) / 32.0
+    wb = BaseArray(w_np.size, w_np.dtype, shape=w_np.shape)
+    xb = BaseArray(x_np.size, x_np.dtype)              # an op-made base
+    ob = BaseArray(4 * 16, w_np.dtype)
+    ops = [Op("matmul", View.contiguous(ob, (4, 16)),
+              (View.contiguous(xb, (4, 8)), View.contiguous(wb, (8, 16))),
+              new_bases=frozenset({ob}))]
+    fn, ins, _ = make_block_fn(ops)
+    assert ins == [xb.uid, wb.uid]
+    assert fn.view_lowerings["stored"] == 1
+    assert fn.stored_bytes == w_np.nbytes
+    jaxpr = jax.make_jaxpr(fn)(jnp.zeros((x_np.size,), jnp.float32),
+                               jnp.asarray(w_np), NO_SALTS).jaxpr
+    w_in = jaxpr.invars[1]
+    reshaped = [e for e in jaxpr.eqns if e.primitive.name == "reshape"]
+    assert reshaped                                   # x is reshaped
+    assert all(w_in not in e.invars for e in reshaped)
+
+    tr = trace.enable()
+    try:
+        with fresh_runtime(loop_fusion=False):
+            w = bh.asarray(w_np)
+            x = bh.ones((4, 8), np.float32) * 0.5
+            got = bh.matmul(x, w).numpy()
+    finally:
+        trace.disable()
+    np.testing.assert_allclose(got, (np.ones((4, 8), np.float32) * 0.5)
+                               @ w_np, rtol=1e-6)
+    blocks = [ev["args"] for ev in tr.events
+              if ev.get("ph") == "X" and ev["name"] == "block"]
+    assert blocks and all("stored_bytes" in a for a in blocks)
+    assert sum(a["stored_bytes"] for a in blocks) == w_np.nbytes
 
 
 def test_stencil_program_uses_fast_path_end_to_end():

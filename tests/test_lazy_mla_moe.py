@@ -299,28 +299,30 @@ def test_validate_config_accepts_deepseek_v2_and_refuses_the_rest():
 #: the dense tiny model's prefill and decode tapes before the expert layer
 #: and latent attention were added: op counts by opcode and the digest of
 #: the whole tape's structural signature (every op, view, literal and free
-#: in order), for the gemma-style and the plain norm scale
+#: in order; since the signature keys each base's shape, with the adopted
+#: weights' own shapes, op for op the tapes they were), for the gemma-style
+#: and the plain norm scale
 DENSE_TAPES = {
     True: {"prefill": ({"add": 13, "copy": 15, "del": 110, "div": 7,
                         "exp": 2, "gather": 1, "matmul": 19, "mul": 38,
                         "reduce_max": 2, "reduce_sum": 7, "rsqrt": 5,
                         "sigmoid": 2, "sub": 6, "sync": 1, "where": 2},
-                       "eb6c88665ec6ad00"),
+                       "9bc06e0fbf0cf81e"),
            "decode": ({"add": 13, "copy": 12, "del": 109, "div": 9,
                        "exp": 2, "gather": 1, "matmul": 19, "mul": 36,
                        "reduce_max": 2, "reduce_sum": 7, "rsqrt": 5,
                        "sigmoid": 2, "sub": 6, "sync": 1, "where": 2},
-                      "41aeda38e0fb1866")},
+                      "5fe3296eb2f7136d")},
     False: {"prefill": ({"add": 13, "copy": 15, "del": 109, "div": 7,
                          "exp": 2, "gather": 1, "matmul": 19, "mul": 37,
                          "reduce_max": 2, "reduce_sum": 7, "rsqrt": 5,
                          "sigmoid": 2, "sub": 6, "sync": 1, "where": 2},
-                        "c5a8d9f82ceaa348"),
+                        "491b707d2bd28639"),
             "decode": ({"add": 13, "copy": 12, "del": 108, "div": 9,
                         "exp": 2, "gather": 1, "matmul": 19, "mul": 35,
                         "reduce_max": 2, "reduce_sum": 7, "rsqrt": 5,
                         "sigmoid": 2, "sub": 6, "sync": 1, "where": 2},
-                       "6371d67c228c5e21")},
+                       "e274bab090a9a7dc")},
 }
 
 

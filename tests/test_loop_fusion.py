@@ -259,6 +259,39 @@ def test_inplace_stencil_bitwise(backend):
     assert ref.tobytes() == got.tobytes()
 
 
+def test_adopted_grid_carry_keeps_its_shape():
+    """An adopted 2-D grid carried by a fused loop: its store buffer keeps
+    the grid's shape through every drain (a ``fori_loop`` carry keeps one
+    shape), and the result is the per-flush one."""
+    import numpy as np
+
+    def run(**rt_kw):
+        with fresh_runtime(**rt_kw) as rt:
+            g = bh.asarray(np.arange(24.0 * 24.0).reshape(24, 24) % 7.0)
+            shapes = []
+            for _ in range(9):
+                inner = (g[1:-1, :-2] + g[1:-1, 2:] + g[:-2, 1:-1]
+                         + g[2:, 1:-1]) * 0.25
+                g[1:-1, 1:-1] = inner
+                inner.delete()
+                bh.flush()
+                buf = rt.buffers.get(g.view.base.uid)
+                shapes.append(None if buf is None else buf.shape)
+            bh.flush()                   # an empty tape drains the loop
+            shapes.append(rt.buffers[g.view.base.uid].shape)
+            out = g.numpy()
+            g._alive = False
+            hist = list(rt.history)
+        return out, shapes, hist
+
+    ref, ref_shapes, _ = run(loop_fusion=False)
+    got, shapes, hist = run(loop_fusion=True, loop_threshold=2,
+                            loop_unroll=4)
+    assert _drains(hist) and _deferred(hist)
+    assert set(ref_shapes) == set(shapes) == {(24, 24)}
+    assert ref.tobytes() == got.tobytes()
+
+
 def test_random_bearing_loop_bitwise():
     """Each deferred iteration's RNG ops must replay their own trace-time
     salts from the stacked salt matrix."""

@@ -578,6 +578,38 @@ class TestServerBatching:
             == self.TENANTS
         assert srv.metrics.counter("serve.batch.dispatches").get() == 1
 
+    def test_adopted_grid_round_trip(self):
+        """Requests that adopt a 2-D grid and read it whole, transposed and
+        in slices: the batched dispatch stacks the grids in their own shape
+        and every answer is the unbatched one, and NumPy's."""
+        rng = np.random.default_rng(5)
+        grids = [np.floor(rng.random((8, 12)) * 16.0)
+                 for _ in range(self.TENANTS)]
+
+        def request(grid):
+            def fn():
+                a = bh.asarray(grid)
+                return (a * 2.0 + a.T.T)[1:-1, 2:] + a[:-2, :-2]
+            return fn
+
+        want = [(g * 2.0 + g)[1:-1, 2:] + g[:-2, :-2] for g in grids]
+        ref_srv = Server(batching=False)
+        refs = [ref_srv.submit(i, request(g)) for i, g in enumerate(grids)]
+        srv = Server(window_s=0.5, max_batch=self.TENANTS)
+        out = {}
+        barrier = threading.Barrier(self.TENANTS)
+
+        def worker(i):
+            barrier.wait()
+            out[i] = srv.submit(i, request(grids[i]))
+
+        _run_threads(self.TENANTS, worker)
+        for i in range(self.TENANTS):
+            np.testing.assert_array_equal(refs[i], want[i])
+            assert refs[i].tobytes() == out[i].tobytes()
+        assert srv.metrics.counter("serve.batch.requests").get() \
+            == self.TENANTS
+
     def test_structurally_distinct_requests_do_not_coalesce(self):
         srv = Server(window_s=0.05, max_batch=4)
         outs = {}
