@@ -40,9 +40,9 @@ fails the run when any gated metric regresses past ``--tolerance``:
   (absolute), p99 submit latency must stay under
   ``serving.TAIL_RATIO_CEILING`` x p50 (absolute), and QPS may not drop
   below the machine-normalized ``base*(1-tol)``;
-* lm: lazy-runtime transformer logits must stay bit-identical to the
-  jitted direct model at every prefill/decode step and the rmsnorm /
-  flash-attention kernel claimants must each claim >= 1 block (absolute);
+* lm: lazy-runtime transformer logits must stay within ``LM_LOGIT_ERR``
+  of the jitted direct model's at every prefill/decode step and the
+  ``rowblock`` backend must claim >= 4 blocks a layer + 1 (absolute);
   lazy per-token decode latency may not exceed the machine-normalized
   ``base*(1+tol)`` plus ``LM_TIME_SLACK_MS``.
 
@@ -69,8 +69,8 @@ the trend):
   loop-fused vs per-flush, with the bitwise-identity check (ISSUE 6
   metric; see ``benchmarks.iterative`` for the two reported times);
 * ``lm``                — transformer prefill wall + per-token decode
-  latency, lazy runtime (``backend="lm"`` claimant stack) vs the jitted
-  direct model, with the bitwise check and per-backend claim counts
+  latency, lazy runtime (``backend="lm"`` stack) vs the jitted direct
+  model, with the logits' relative error and per-backend block counts
   (ISSUE 10 metric);
 * ``obs``               — disabled-tracing span overhead (ns/call) and the
   span-count profile of one canonical traced flush (ISSUE 7 metric);
@@ -295,15 +295,15 @@ def snap_partition_quality(quick: bool) -> Dict:
 def snap_lm(quick: bool) -> Dict:
     """ISSUE 10 metric: LM serving through the lazy runtime vs the jitted
     direct model — per-token decode latency and prefill wall, with the
-    bitwise-identity check and the kernel-claimant block counts.
+    logits' relative error and the per-backend block counts.
 
     The latency ratio is *diagnostic* (the lazy path pays tracing +
-    planning per step and runs its claimed kernels in Pallas interpret
-    mode on CPU); what the ``--compare`` gate holds absolute is the
-    contract: bit-identical logits at every step, and the rmsnorm /
-    flash-attention claimants actually claiming blocks.  Lazy decode
-    latency is additionally gated against the machine-normalized
-    baseline."""
+    planning per step and runs its row-replay kernels in Pallas interpret
+    mode on CPU); what the ``--compare`` gate holds absolute is
+    correctness: logits within ``LM_LOGIT_ERR`` of the direct model's at
+    every step, and the ``rowblock`` backend taking the rmsnorm and
+    softmax blocks.  Lazy decode latency is additionally gated against
+    the machine-normalized baseline."""
     import jax
     import numpy as np
 
@@ -324,23 +324,28 @@ def snap_lm(quick: bool) -> Dict:
     step_toks = [rng.integers(0, cfg.vocab_size, (b, 1)).astype(np.int32)
                  for _ in range(steps)]
 
-    # -- direct jitted serving (the reference: timing AND bits) ----------
-    prefill = jax.jit(lambda p, t: T.serve_prefill(p, t, cfg, max_seq))
-    decode = jax.jit(lambda p, c, t: T.serve_decode(p, c, t, cfg))
-    ref_logits, caches0 = prefill(params, tokens)           # compile
-    jax.block_until_ready(ref_logits)
-    t0 = time.perf_counter()
-    ref_logits, caches0 = prefill(params, tokens)
-    jax.block_until_ready(ref_logits)
-    prefill_ms_direct = (time.perf_counter() - t0) * 1e3
-    jax.block_until_ready(decode(params, caches0, step_toks[0]))  # compile
-    ref_steps, t_direct, caches = [], [], caches0
-    for tok in step_toks:
+    # -- direct jitted serving (the reference: timing AND logits) --------
+    with jax.default_matmul_precision("highest"):
+        prefill = jax.jit(lambda p, t: T.serve_prefill(p, t, cfg, max_seq))
+        decode = jax.jit(lambda p, c, t: T.serve_decode(p, c, t, cfg))
+        ref_logits, caches0 = prefill(params, tokens)           # compile
+        jax.block_until_ready(ref_logits)
         t0 = time.perf_counter()
-        lg, caches = decode(params, caches, tok)
-        jax.block_until_ready(lg)
-        t_direct.append((time.perf_counter() - t0) * 1e3)
-        ref_steps.append(np.asarray(lg))
+        ref_logits, caches0 = prefill(params, tokens)
+        jax.block_until_ready(ref_logits)
+        prefill_ms_direct = (time.perf_counter() - t0) * 1e3
+        jax.block_until_ready(decode(params, caches0, step_toks[0]))
+        ref_steps, t_direct, caches = [], [], caches0
+        for tok in step_toks:
+            t0 = time.perf_counter()
+            lg, caches = decode(params, caches, tok)
+            jax.block_until_ready(lg)
+            t_direct.append((time.perf_counter() - t0) * 1e3)
+            ref_steps.append(np.asarray(lg))
+
+    def rel(got, want) -> float:
+        got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+        return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
 
     # -- lazy runtime: one flushed tape per prefill/decode step ----------
     lt = LazyTransformer(params, cfg)
@@ -348,13 +353,13 @@ def snap_lm(quick: bool) -> Dict:
     t0 = time.perf_counter()
     got_logits = lt.prefill(tokens, max_seq)
     prefill_ms_lazy = (time.perf_counter() - t0) * 1e3
-    identical = np.asarray(ref_logits).tobytes() == got_logits.tobytes()
+    logit_err = rel(got_logits, ref_logits)
     t_lazy = []
     for i, tok in enumerate(step_toks):
         t0 = time.perf_counter()
         lg = lt.decode(tok)
         t_lazy.append((time.perf_counter() - t0) * 1e3)
-        identical = identical and ref_steps[i].tobytes() == lg.tobytes()
+        logit_err = max(logit_err, rel(lg, ref_steps[i]))
     claims = dict(lt.rt.executor.stats["backend_blocks"])
 
     def med(xs: List[float]) -> float:
@@ -363,7 +368,7 @@ def snap_lm(quick: bool) -> Dict:
     out = {"config": {"n_layers": cfg.n_layers, "d_model": cfg.d_model,
                       "n_heads": cfg.n_heads, "d_ff": cfg.d_ff,
                       "batch": b, "prompt": s, "max_seq": max_seq},
-           "steps": steps, "bit_identical": bool(identical),
+           "steps": steps, "logit_err": logit_err,
            "prefill_ms_direct": prefill_ms_direct,
            "prefill_ms_lazy": prefill_ms_lazy,
            "decode_ms_direct": med(t_direct),
@@ -373,9 +378,8 @@ def snap_lm(quick: bool) -> Dict:
           f"{prefill_ms_direct:.1f}ms direct; decode "
           f"{out['decode_ms_lazy']:.1f}ms vs "
           f"{out['decode_ms_direct']:.1f}ms/token; "
-          f"claimed rmsnorm={claims.get('rmsnorm', 0)} "
-          f"flash_attention={claims.get('flash_attention', 0)}, "
-          f"identical={identical}", flush=True)
+          f"rowblock={claims.get('rowblock', 0)}, "
+          f"logit_err={logit_err!r}", flush=True)
     return out
 
 
@@ -425,6 +429,11 @@ OBS_SPAN_NS_CEILING = 100.0
 # times are ~100ms of tracing + planning Python, and CI scheduler jitter
 # alone can add a large fraction of that.
 LM_TIME_SLACK_MS = 100.0
+# largest max|lazy - direct| / max|direct| of the lm snapshot's
+# logits, the benchmark's own limit: the two paths sum the same float32
+# products in different orders (a few 1e-7), bfloat16 products move them
+# by 1e-5 or more.
+LM_LOGIT_ERR = 3e-6
 
 
 def machine_ref_s() -> float:
@@ -550,18 +559,18 @@ def compare_snapshots(snap: Dict, base: Dict, tolerance: float) -> List[str]:
     if span_ns is not None and span_ns > OBS_SPAN_NS_CEILING:
         fails.append(f"obs: disabled span() costs {span_ns:.0f}ns/call > "
                      f"{OBS_SPAN_NS_CEILING:.0f}ns ceiling")
-    # lm (ISSUE 10): the bitwise contract and the claimant adoption are
+    # lm: the logits' error and the rowblock claims are
     # absolute; lazy decode latency takes the machine-normalized tolerance
     lm = snap.get("lm", {})
     if lm:
-        if not lm.get("bit_identical", True):
-            fails.append("lm: lazy transformer logits not bit-identical "
-                         "to the jitted direct model")
+        if lm.get("logit_err", 0.0) > LM_LOGIT_ERR:
+            fails.append(f"lm: lazy transformer logits {lm['logit_err']!r} "
+                         f"off the jitted direct model's (> {LM_LOGIT_ERR})")
         bb = lm.get("backend_blocks", {})
-        for name in ("rmsnorm", "flash_attention"):
-            if bb.get(name, 0) < 1:
-                fails.append(f"lm: the {name!r} claimant never claimed a "
-                             f"block (backend_blocks={bb})")
+        want = 4 * lm.get("config", {}).get("n_layers", 0) + 1
+        if bb.get("rowblock", 0) < want:
+            fails.append(f"lm: rowblock claimed fewer than {want} blocks "
+                         f"(backend_blocks={bb})")
         b_lm = base.get("lm", {})
         if b_lm.get("decode_ms_lazy") and lm.get("decode_ms_lazy") is not None:
             limit = b_lm["decode_ms_lazy"] * ratio * (1.0 + tolerance) \

@@ -23,7 +23,7 @@ calls, on one TPU chip:
 the ``shard_map`` backend, checked against a one-device run.
 
 Every phase asserts that kernels compiled to Mosaic (no interpret mode),
-that Pallas or an LM claimant ran blocks, and that no backend failed.
+that Pallas or the rowblock backend ran blocks, and that no backend failed.
 Detail goes to earlier lines; the last line of stdout is one JSON object
 naming the device.  The script exits non-zero on the first failure, and
 when JAX finds no TPU: it never falls back to the CPU.  The seconds it
@@ -266,10 +266,9 @@ def phase_lm(cfg=None, batch: int = 2, prompt: int = 512, steps: int = 3,
         st = lowering_report(lt.rt, "lm prefill")
         claims = st["backend_blocks"]
         L = cfg.n_layers
-        if claims.get("rmsnorm", 0) < 2 * L + 1 \
-                or claims.get("flash_attention", 0) < 2 * L:
-            raise SmokeFailure(f"lm: claimants took {claims}, want rmsnorm "
-                               f">= {2 * L + 1}, flash_attention >= {2 * L}")
+        if claims.get("rowblock", 0) < 4 * L + 1:
+            raise SmokeFailure(f"lm: rowblock took {claims}, want "
+                               f">= {4 * L + 1}")
         check_close("lm prefill logits", got, refs[0], LM_RTOL)
         for s in range(steps):
             t0 = time.perf_counter()

@@ -1,33 +1,26 @@
-"""Hand-written-kernel lowering claimants for LM blocks (DESIGN.md §20).
+"""The ``rowblock`` lowering backend for LM blocks (DESIGN.md §20).
 
-Three backends — ``flash_attention``, ``rmsnorm``, ``mamba_scan`` — wrap
-the kernels under ``repro.kernels.*`` as first-class lowering backends:
-each one *claims* a fusion block when (a) its op-pattern matcher
-(``kernels.<name>.block.match``) recognizes the block's opcode shape and
-(b) the row-replay codegen (``kernels.fused_block.rowblock``) can express
-it as one row-tiled Pallas kernel.  Blocks outside the pattern decline
-with the matcher's slug (``no_softmax`` / ``no_rmsnorm`` / ``no_scan``);
-pattern-shaped blocks the tiler cannot express decline with the codegen
-reason, so fallback stats separate "not mine" from "mine but
-inexpressible".
+The WSP fuse rule ends a block at every reduction, so a transformer tape
+partitions into small reduction blocks whose results feed later ops of the
+same block through a broadcast (``exp(x - max)``, ``x * rsqrt(var)``).
+The generic ``pallas`` tiler declines those (``view_conflict``); the
+row-replay codegen (``kernels.fused_block.rowblock``) expresses them as
+one row-tiled Pallas kernel.  This backend claims a block when
 
-Pricing: one dispatch per claimed block, the same price the generic
-``pallas`` backend quotes when it can also express the block — the tie
-is broken by the ``lm`` stack's preference order (claimants first), so a
-matched block always runs the hand-written path.  When the generic tiler
-declines (``view_conflict`` on blocks that consume an in-block reduction
-through a broadcast view — the shape the row-replay codegen exists for)
-the claimant wins outright over the 2-dispatch XLA fallback under any
-cost model's ``dispatch_price``.
+1. its opcodes pass one of the row-pattern screens below (a masked
+   softmax piece, an rmsnorm scale chain or an exp-gated scan update),
+   else it declines with ``no_row_pattern``;
+2. on a TPU, Mosaic can compile its opcodes and dtypes (``mosaic_reason``);
+3. the row-replay codegen can express it (``rowblock_lower_reason``).
 
-Bit-identity note: the claimants lower through the row-replay generator —
-the same jnp op tables as the XLA fallback, applied in the same per-row
-order — NOT through the hand-written kernel bodies in
-``kernels/*/kernel.py``.  The flash kernel's online-softmax rewrite
-``(p @ v) / l`` differs from XLA's ``(p / l) @ v`` in the last ulp; the
-claim protocol requires results bitwise-identical to the XLA fallback, so
-the kernels' *claim boundary* (the matchers) and the *replay* lowering
-are what ship here.
+A claimed block costs one dispatch, the price the generic ``pallas``
+backend quotes when it can also express the block; the ``lm`` stack's
+order (``rowblock`` first) breaks that tie.  Where ``pallas`` declines,
+``rowblock`` wins outright over the two-dispatch XLA fallback.
+
+The row-replay kernel applies the same jnp op tables as the XLA fallback
+(``make_block_fn``) to each complete row, so its results agree with XLA's
+to float32 rounding; tests hold them to a stated tolerance.
 """
 
 from __future__ import annotations
@@ -37,15 +30,48 @@ from typing import Optional, Sequence, Tuple
 
 from .base import LoweringBackend, LoweringContext
 
+#: a masked-softmax piece: the masked max ``where -> reduce_max``, or the
+#: shifted exponentials and their sum ``sub -> exp -> reduce_sum`` (a bare
+#: exp + sum without the max subtraction is a scan shape)
+_SOFTMAX_OPS = {"mul", "div", "where", "sub", "add", "neg", "exp",
+                "reduce_max", "reduce_sum", "copy", "maximum"}
+_SOFTMAX_ANCHORS = ({"where", "reduce_max"}, {"sub", "exp", "reduce_sum"})
+#: the rmsnorm scale chain ``div -> add(eps) -> rsqrt -> mul -> mul``,
+#: anchored on ``rsqrt`` so plain sums of squares are not claimed
+_NORM_OPS = {"add", "sub", "mul", "div", "rsqrt", "sqrt", "square",
+             "reciprocal", "reduce_sum", "copy"}
+_NORM_ANCHORS = {"rsqrt", "mul"}
+#: an exp-gated scan update ``exp(decay) * state + gate * input`` with at
+#: most one trailing contraction
+_SCAN_OPS = {"exp", "add", "sub", "mul", "div", "neg", "reduce_sum", "copy"}
+_SCAN_ANCHORS = {"exp", "add"}
+
+
+def row_pattern_reason(ops: Sequence) -> Optional[str]:
+    """``None`` when the block's opcodes pass one of the row-pattern
+    screens, else ``"no_row_pattern"``.  A pure opcode screen, cheap
+    enough to run on every block; whether the row replay can express the
+    block is :func:`rowblock_lower_reason`'s question."""
+    work = [op.opcode for op in ops if not op.is_system()]
+    seen = set(work)
+    if seen <= _SOFTMAX_OPS and any(a <= seen for a in _SOFTMAX_ANCHORS):
+        return None
+    if seen <= _NORM_OPS and _NORM_ANCHORS <= seen:
+        return None
+    if (seen <= _SCAN_OPS and _SCAN_ANCHORS <= seen
+            and work.count("mul") >= 2 and work.count("reduce_sum") <= 1):
+        return None
+    return "no_row_pattern"
+
+
 _ROW_MEMO: "OrderedDict[Tuple, Optional[str]]" = OrderedDict()
 _ROW_MEMO_CAP = 4096
 
 
 def rowblock_lower_reason(ops: Sequence, plan) -> Optional[str]:
-    """Memoized row-replay expressibility, keyed like
-    :func:`repro.core.backends.base.pallas_lower_reason` on the plan's
-    structural signature — all three claimants consult it during one
-    selection, so the second and third lookups are free."""
+    """Row-replay expressibility, memoized on the plan's structural
+    signature like :func:`repro.core.backends.base.pallas_lower_reason`, so
+    a block planned again after a merge-cache miss is not re-analysed."""
     key = getattr(plan, "signature", None)
     if key is not None and key in _ROW_MEMO:
         _ROW_MEMO.move_to_end(key)
@@ -59,16 +85,14 @@ def rowblock_lower_reason(ops: Sequence, plan) -> Optional[str]:
     return reason
 
 
-class _RowKernelBackend(LoweringBackend):
-    """Shared machinery: matcher screen, then row-replay claim + build."""
+class RowBlockBackend(LoweringBackend):
+    """Row-pattern screen, then the row-replay claim and build."""
 
+    name = "rowblock"
     donates = False      # operands may be read through broadcast views
 
-    def _match(self, ops: Sequence) -> Optional[str]:
-        raise NotImplementedError
-
     def claims(self, ops: Sequence, plan, ctx: LoweringContext) -> Optional[str]:
-        reason = self._match(ops)
+        reason = row_pattern_reason(ops)
         if reason is None and not ctx.interpret:
             from ...kernels.fused_block.codegen import mosaic_reason
             reason = mosaic_reason(ops)
@@ -84,31 +108,6 @@ class _RowKernelBackend(LoweringBackend):
         return fn
 
 
-class FlashAttentionBackend(_RowKernelBackend):
-    name = "flash_attention"
-
-    def _match(self, ops: Sequence) -> Optional[str]:
-        from ...kernels.flash_attention.block import match
-        return match(ops)
-
-
-class RMSNormBackend(_RowKernelBackend):
-    name = "rmsnorm"
-
-    def _match(self, ops: Sequence) -> Optional[str]:
-        from ...kernels.rmsnorm.block import match
-        return match(ops)
-
-
-class MambaScanBackend(_RowKernelBackend):
-    name = "mamba_scan"
-
-    def _match(self, ops: Sequence) -> Optional[str]:
-        from ...kernels.mamba_scan.block import match
-        return match(ops)
-
-
-#: preference order of the ``backend="lm"`` stack: specific claimants
-#: first (most selective matcher wins ties), generic codegen, XLA floor
-LM_STACK: Tuple[str, ...] = ("flash_attention", "rmsnorm", "mamba_scan",
-                             "pallas", "xla")
+#: preference order of the ``backend="lm"`` stack: the row replay first,
+#: then the generic codegen, then the XLA floor
+LM_STACK: Tuple[str, ...] = ("rowblock", "pallas", "xla")

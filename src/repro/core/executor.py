@@ -654,10 +654,8 @@ class BlockExecutor:
         with self._lock:
             cached = self._cache.get(key)
         if cached is not None:
-            self.stats.inc("exec_cache_hits")
             trace.instant("cache.exec", hit=True, backend=decision.backend)
             return (*cached, True)
-        self.stats.inc("exec_cache_misses")
         trace.instant("cache.exec", hit=False, backend=decision.backend)
         with trace.span("build", backend=decision.backend,
                         n_ops=len(ops)):
@@ -683,13 +681,23 @@ class BlockExecutor:
                 self._counted[name] = counted
         return (*entry, False)
 
-    def _account(self, decision, plan, donates: bool) -> None:
+    def _count_dispatch(self, warm: bool) -> None:
+        """Count one executable dispatch in ``blocks_run`` and its cache
+        lookup in ``exec_cache_hits`` or ``exec_cache_misses``, both under
+        the registry lock, so ``hits + misses == blocks_run`` holds in
+        every snapshot.  A loop or batch executable counts once, however
+        many blocks it runs."""
+        with self.metrics.lock:
+            self.stats.inc("blocks_run")
+            self.stats.inc("exec_cache_hits" if warm else "exec_cache_misses")
+
+    def _account(self, decision, plan, donates: bool, warm: bool) -> None:
         """Uniform per-dispatch stats plus the legacy aliases.  Every update
         is an atomic ``StatsView.inc`` — concurrent session flushes
         (DESIGN.md §18) must not lose increments to read-modify-write
         races, and the stress suite asserts exact totals."""
         st = self.stats
-        st.inc("blocks_run")
+        self._count_dispatch(warm)
         st.inc("backend_blocks", labels=(decision.backend,))
         for name, reason in decision.declined:
             st.inc("backend_fallbacks", labels=(name, reason))
@@ -736,7 +744,7 @@ class BlockExecutor:
                     # flushes.
                     fn, donates, name, views, warm = self._executable(
                         decision, ops, plan, ctx)
-                    self._account(decision, plan, donates)
+                    self._account(decision, plan, donates, warm)
                     counted = self._counted.get(name)
                     extra = {}
                     if counted is not None:
@@ -838,12 +846,11 @@ class BlockExecutor:
         with trace.span("stage.execute", loop=True, n_iterations=int(n)):
             with self._lock:
                 cached = self._cache.get(key)
+            self._count_dispatch(cached is not None)
             if cached is not None:
-                self.stats.inc("exec_cache_hits")
                 trace.instant("cache.exec", hit=True, loop=True)
                 fn = cached[0]
             else:
-                self.stats.inc("exec_cache_misses")
                 trace.instant("cache.exec", hit=False, loop=True)
                 with trace.span("build", loop=True,
                                 n_ops=len(loop_plan.tape)):
@@ -891,12 +898,11 @@ class BlockExecutor:
         with trace.span("serve.batch", n_requests=B):
             with self._lock:
                 cached = self._cache.get(key)
+            self._count_dispatch(cached is not None)
             if cached is not None:
-                self.stats.inc("exec_cache_hits")
                 trace.instant("cache.exec", hit=True, batch=True)
                 fn, n_rand = cached
             else:
-                self.stats.inc("exec_cache_misses")
                 trace.instant("cache.exec", hit=False, batch=True)
                 with trace.span("build", batch=True,
                                 n_ops=len(schedule.tape)):

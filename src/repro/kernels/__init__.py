@@ -1,5 +1,7 @@
 """Pallas kernels: the fused-block codegen behind the runtime's ``pallas``
-and LM-claimant backends, and the hand-written LM kernels."""
+backend and its row-replay variant behind ``rowblock``, plus plain jnp
+references (attention, add+rmsnorm, the mamba and rwkv6 scans) that tests
+and the direct model compare against."""
 
 from __future__ import annotations
 

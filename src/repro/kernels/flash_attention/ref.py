@@ -1,4 +1,4 @@
-"""Pure-jnp oracle for flash attention (naive materialized softmax)."""
+"""Pure-jnp reference attention (naive materialized softmax)."""
 
 from __future__ import annotations
 

@@ -64,8 +64,8 @@ from jax.experimental import pallas as pl
 from .. import resolve_interpret
 
 # the kernel body evaluates ops with the SAME jnp tables as the XLA
-# fallback (make_block_fn) — importing them is what makes the bit-identity
-# contract a structural property rather than a convention to maintain
+# fallback (make_block_fn) — importing them is what makes agreement with
+# the fallback a structural property rather than a convention to maintain
 from ...core.executor import (_BINARY, _REDUCE as _REDUCE_FN, _UNARY, _read,
                               _slice_plan, _write, block_io)
 from ...core.ir import COMM_OPS, REDUCTIONS, Op, View

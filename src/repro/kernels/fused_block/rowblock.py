@@ -1,10 +1,10 @@
-"""Row-replay Pallas codegen — the lowering engine behind the hand-written
-kernel claimants (flash-attention / rmsnorm / mamba-scan backends).
+"""Row-replay Pallas codegen — the lowering engine behind the ``rowblock``
+backend (``core/backends/lm.py``).
 
 The generic tiler (``codegen.py``) refuses any block that READS an
 in-block reduction output (``view_conflict``): its grid may split a
 reduction across slabs, so the reduced value is not available in-register
-when a later op wants it.  But the LM blocks those kernels exist for —
+when a later op wants it.  But the LM blocks ``rowblock`` claims —
 masked softmax, rmsnorm, exponential scans — are exactly reductions whose
 results feed later ops *in the same block* (``exp(x - max)``,
 ``x * rsqrt(mean)``).  This generator closes that gap for the one shape
@@ -22,8 +22,8 @@ discards).  A later read of the reduction output resolves to
 view with a stride-0 axis appended — exactly the
 ``var.reshape(b, s, 1).broadcast_to((b, s, d))`` pattern the lazy
 frontend records — replaying the same jnp ops the XLA fallback
-(``make_block_fn``) runs, in the same per-row order, so results stay
-bit-identical.
+(``make_block_fn``) runs, in the same per-row order, so results agree
+with the fallback's up to float32 rounding.
 
 Everything else (operand classification, slice-planned views, VMEM
 budgeting, the ``fn(*bufs, salts)`` calling convention) is shared with

@@ -126,8 +126,7 @@ def _chunked_attn(q, k, v, *, causal: bool, window: Optional[int],
                   softcap: Optional[float], scale: float,
                   chunk: int = 2048) -> jnp.ndarray:
     """Flash-style online-softmax attention in pure XLA: lax.scan over query
-    chunks so no (S, S) score matrix is ever live (memory-roofline measure;
-    the Pallas kernel in repro.kernels.flash_attention is the TPU variant).
+    chunks so no (S, S) score matrix is ever live (memory-roofline measure).
 
     q: (B, S, H, D) grouped-query; k, v: (B, T, Hkv, D).
     """

@@ -44,9 +44,9 @@ Checks (each returns normally or raises ``AssertionError``):
   single device (COMM as identity copies);
 * ``check_lm``    — an LM-shaped program (:class:`LMProgram` grammars:
   rmsnorm / masked-softmax attention / MoE top-k routing / selective
-  scan) run on the ``backend="lm"`` claimant stack is bitwise identical
-  to the plain XLA stack under the SAME greedy partition, and the
-  grammar's hand-written kernel claimant actually claimed a block.
+  scan) run on the ``backend="lm"`` stack agrees with the plain XLA stack
+  under the SAME greedy partition to ``LM_RTOL``, and the ``rowblock``
+  backend claimed a block of every grammar but moe.
 
 CLI sweep (the CI fuzz job)::
 
@@ -378,26 +378,24 @@ class LMProgram:
     """A seeded LM-shaped lazy program (DESIGN.md §20).
 
     Four grammars, chosen by ``seed % 4``, each tracing the op shapes the
-    LM kernel claimants pattern-match — sized by the seed so the sweep
-    covers many domains:
+    ``rowblock`` backend's row-pattern screen admits — sized by the seed
+    so the sweep covers many domains:
 
     * ``rmsnorm``    — residual add, sum-of-squares variance, the
-      ``div→add(eps)→rsqrt→mul→mul`` scale chain (``rmsnorm`` claimant);
+      ``div→add(eps)→rsqrt→mul→mul`` scale chain;
     * ``attention``  — scaled masked scores, ``where(-inf)``, the
-      max / shifted-exp / sum / normalize softmax chain
-      (``flash_attention`` claimant, two claimed reduction blocks);
+      max / shifted-exp / sum / normalize softmax chain (two claimed
+      reduction blocks);
     * ``moe``        — top-k expert routing: host-computed argsort
       indices, ``take`` gathers out of an expert table, gate-weighted
-      combine (gathers stay on the XLA floor — no claimant);
+      combine (gathers stay on the XLA floor — nothing claims them);
     * ``scan``       — a selective-scan step ``exp(dtA)*h + gate*u``
-      with a trailing contraction (``mamba_scan`` claimant).
+      with a trailing contraction.
 
     Leaves are integer-valued float32 (``floor(u * 16) - 8``), so sums of
-    squares and masked maxima are exact; transcendentals (``rsqrt`` /
-    ``exp``) receive identical input bits on every path and the softmax /
-    scan reductions are row-local in both the claimants' row-replay
-    kernels and the XLA block fallback — which is precisely the bitwise
-    contract ``check_lm`` exercises.
+    squares and masked maxima are exact; the softmax and scan reductions
+    are row-local in both the row-replay kernels and the XLA block
+    fallback.
     """
 
     GRAMMARS = ("rmsnorm", "attention", "moe", "scan")
@@ -671,22 +669,26 @@ def check_serve(seed: int, *, tenants: int = 4, requests: int = 2,
         f"seed {seed}: no request ever coalesced (window too small?)"
 
 
-#: grammar -> the hand-written kernel claimant that must claim >= 1 block
-#: (moe is gather-dominated: no claimant, the bitwise check is the point)
-_LM_CLAIMANTS = {"rmsnorm": "rmsnorm", "attention": "flash_attention",
-                 "scan": "mamba_scan"}
+#: the grammars whose blocks the ``rowblock`` backend must claim (moe is
+#: gather-dominated and stays on the XLA floor)
+_ROW_GRAMMARS = ("rmsnorm", "attention", "scan")
+#: largest ``max|lm - xla| / max|xla|`` of an output: both stacks run the
+#: same jnp ops on the same rows, so they differ only where one contracts
+#: a multiply-add into one rounding or sums a row in another order — a
+#: few float32 ulps (~1e-7) — while a wrong op, row or mask moves the
+#: result by far more than 1e-6
+LM_RTOL = 1e-6
 
 
 def check_lm(seed: int, *, size: int = 64) -> None:
-    """LM claimant stack == XLA stack, bitwise, under the SAME partition.
+    """LM stack == XLA stack to ``LM_RTOL``, under the SAME partition.
 
     Both runs use greedy/bohrium — partitioning is backend-independent, so
     the two stacks lower the *identical* block sequence and the comparison
-    is exactly the claimant protocol's contract: a hand-written kernel may
-    claim a block only if its result is bit-identical to the XLA fallback
-    (DESIGN.md §20).  For the grammars with a matching claimant the check
-    also asserts the claim actually happened — a silently-declining
-    matcher would otherwise turn this into XLA vs XLA."""
+    isolates the ``rowblock`` lowering from the XLA fallback (DESIGN.md
+    §20).  For the grammars it should claim, the check also asserts the
+    claim happened — a silently-declining screen would otherwise turn this
+    into XLA vs XLA."""
     from repro.core.lazy import fresh_runtime
     prog = LMProgram(seed, size=size)
     kw = dict(algorithm="greedy", cost_model="bohrium", loop_fusion=False)
@@ -694,12 +696,17 @@ def check_lm(seed: int, *, size: int = 64) -> None:
     with fresh_runtime(backend="lm", **kw) as rt:
         got = prog._trace(rt)
         blocks = dict(rt.executor.stats.get("backend_blocks", {}))
-    _assert_bitwise(ref, got, f"seed {seed} [lm/{prog.grammar} vs xla]")
-    claimant = _LM_CLAIMANTS.get(prog.grammar)
-    if claimant is not None:
-        assert blocks.get(claimant, 0) >= 1, (
+    label = f"seed {seed} [lm/{prog.grammar} vs xla]"
+    assert len(ref) == len(got), f"{label}: {len(ref)} vs {len(got)} outputs"
+    for i, (r, g) in enumerate(zip(ref, got)):
+        assert r.dtype == g.dtype and r.shape == g.shape, f"{label}: {i}"
+        err = float(np.max(np.abs(g.astype(np.float64) - r))
+                    / np.max(np.abs(r)))
+        assert err <= LM_RTOL, f"{label}: output {i} rel. error {err!r}"
+    if prog.grammar in _ROW_GRAMMARS:
+        assert blocks.get("rowblock", 0) >= 1, (
             f"seed {seed}: grammar {prog.grammar!r} never exercised the "
-            f"{claimant!r} claimant (backend_blocks={blocks})")
+            f"'rowblock' backend (backend_blocks={blocks})")
 
 
 CHECKS = {"graph": check_graph, "exec": check_exec, "dist": check_dist,

@@ -203,12 +203,12 @@ def test_single_flush_mixes_shard_map_and_xla():
 
 
 # ---------------------------------------------------------------------------
-# LM kernel claimants (ISSUE 10 satellite)
+# the LM row-replay backend
 # ---------------------------------------------------------------------------
 
 def _rmsnorm_scale_tape():
-    """A recorded block both the ``rmsnorm`` claimant and generic Pallas
-    can express: the div→add(eps)→rsqrt→mul scale chain on a 2-D domain."""
+    """A recorded block both ``rowblock`` and generic Pallas can express:
+    the div→add(eps)→rsqrt→mul scale chain on a 2-D domain."""
     with fresh_runtime() as rt:
         x = bh.asarray(np.arange(64.0).reshape(8, 8) + 1.0)
         y = x * bh.rsqrt(x / 8.0 + 1e-6)
@@ -220,32 +220,31 @@ def _rmsnorm_scale_tape():
 
 
 def test_lm_stack_resolution():
-    assert default_stack("lm") == ("flash_attention", "rmsnorm",
-                                   "mamba_scan", "pallas", "xla")
-    assert {"flash_attention", "rmsnorm", "mamba_scan"} \
-        <= set(available_backends())
+    assert default_stack("lm") == ("rowblock", "pallas", "xla")
+    assert "rowblock" in available_backends()
 
 
 def test_claimant_and_pallas_tie_broken_by_stack_order():
-    """A block claimed by BOTH a hand-written kernel claimant and generic
-    Pallas prices identically (one dispatch each); preference order is the
-    deterministic tie-break — flipping the stack flips the winner."""
+    """A block claimed by BOTH ``rowblock`` and generic Pallas prices
+    identically (one dispatch each); preference order is the deterministic
+    tie-break — flipping the stack flips the winner."""
     tape = _rmsnorm_scale_tape()
     plan = next(p for p in _plans(tape) if p.has_work)
     ops = [tape[i] for i in plan.op_indices]
     ctx = LoweringContext()
-    d = select_lowering(ops, plan, ("rmsnorm", "pallas", "xla"), ctx)
-    assert d.backend == "rmsnorm"
+    d = select_lowering(ops, plan, ("rowblock", "pallas", "xla"), ctx)
+    assert d.backend == "rowblock"
     assert d.reason_for("pallas") is None       # pallas claimed, just lost
-    d = select_lowering(ops, plan, ("pallas", "rmsnorm", "xla"), ctx)
+    d = select_lowering(ops, plan, ("pallas", "rowblock", "xla"), ctx)
     assert d.backend == "pallas"
-    assert d.reason_for("rmsnorm") is None
-    # non-matching claimants decline with their matcher slug
-    d = select_lowering(ops, plan,
-                        ("flash_attention", "mamba_scan", "xla"), ctx)
+    assert d.reason_for("rowblock") is None
+    # a block outside the row patterns is declined with the screen's slug
+    plain = _simple_tape()
+    plan = next(p for p in _plans(plain) if p.has_work)
+    d = select_lowering([plain[i] for i in plan.op_indices], plan,
+                        ("rowblock", "xla"), ctx)
     assert d.backend == "xla"
-    assert d.reason_for("flash_attention") == "no_softmax"
-    assert d.reason_for("mamba_scan") == "no_scan"
+    assert d.reason_for("rowblock") == "no_row_pattern"
 
 
 def test_claimant_builder_failure_raises_and_names_backend():

@@ -80,8 +80,13 @@ def lt():
 
 def test_prefill_logits_match_the_reference(lt, weights):
     tokens = _tokens(40)
+    lt.rt.executor.reset_stats()
     with jax.default_matmul_precision("highest"):
         got = lt.prefill(tokens, 48)
+    # the prefill's blocks by backend: rowblock takes its 8 softmax and 7
+    # rmsnorm blocks
+    assert dict(lt.rt.executor.stats["backend_blocks"]) \
+        == {"rowblock": 15, "pallas": 67, "xla": 52}
     want, gaps, _ = REF.forward(weights, tokens[0], CFG)
     assert min(g.min() for g in gaps) > 1e-3, "no near tie in the prompt"
     assert got.shape == (1, 1, CFG["vocab_size"])

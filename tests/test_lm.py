@@ -1,14 +1,17 @@
 """LM workloads through the lazy runtime (ISSUE 10, DESIGN.md §20).
 
-The tentpole contract: a tiny-config transformer forward / prefill /
-decode step traced through :class:`repro.models.lazy_transformer
-.LazyTransformer` flushes as one tape per call and produces logits (and KV
-caches) BITWISE identical to the jitted direct model — while the
-``backend="lm"`` stack lowers the rmsnorm and masked-softmax blocks
-through the hand-written kernel claimants (asserted via executor stats and
-the explain report).  The reference is the *jitted* direct call: XLA
-contracts mul+add into FMA under jit, and block-granularity execution
-reproduces those bits exactly (see the ``lazy_transformer`` module doc).
+A tiny-config transformer forward / prefill / decode step traced through
+:class:`repro.models.lazy_transformer.LazyTransformer` flushes as one tape
+per call, and its logits and KV caches agree with the jitted direct model
+at float32 ``highest`` to ``LOGIT_ERR``, while the ``backend="lm"`` stack
+lowers the rmsnorm and masked-softmax blocks through the ``rowblock``
+backend (asserted via executor stats and the explain report).
+
+Tolerance: ``LOGIT_ERR`` is the benchmark's own limit on max|got - ref| /
+max|ref|.  The program and the direct model sum the same float32 products
+in different blocks and orders, which moves logits by a few 1e-7; products
+in bfloat16 move them by 1e-5 or more (``test_lazy_mla_moe.py``'s
+control).  Bits are held only between two runs of the program.
 """
 
 import jax
@@ -26,6 +29,8 @@ CFG = ModelConfig(name="lm_tiny", family="dense", n_layers=2, d_model=32,
                   tie_embeddings=False)
 TOKENS = np.asarray([[3, 14, 15, 92, 65, 35], [8, 9, 79, 3, 2, 38]], np.int32)
 MAX_SEQ = 16
+#: relative error of the logits (module doc)
+LOGIT_ERR = 3e-6
 
 
 @pytest.fixture(scope="module")
@@ -43,48 +48,66 @@ def _claims(rt) -> dict:
     return dict(rt.executor.stats.get("backend_blocks", {}))
 
 
+def _rel(got, want) -> float:
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+
+
+def _jit_highest(fn):
+    jitted = jax.jit(fn)
+
+    def run(*args):
+        with jax.default_matmul_precision("highest"):
+            return jitted(*args)
+    return run
+
+
 def test_forward_bitwise_identical_to_jitted_direct(params, lt):
-    ref = jax.jit(lambda p, t: T.forward(p, t, CFG)[0])(params, TOKENS)
+    """Within ``LOGIT_ERR`` of the jitted direct forward; bitwise equal
+    across two runs of the program."""
+    ref = _jit_highest(lambda p, t: T.forward(p, t, CFG)[0])(params, TOKENS)
     got = lt.forward(TOKENS)
     assert got.dtype == np.float32 and got.shape == ref.shape
-    assert np.asarray(ref).tobytes() == got.tobytes()
+    assert _rel(got, ref) <= LOGIT_ERR
+    assert lt.forward(TOKENS).tobytes() == got.tobytes()
 
 
-def test_forward_lowers_through_kernel_claimants(lt):
+def test_forward_lowers_through_rowblock(lt):
     lt.forward(TOKENS)
     claims = _claims(lt.rt)
     # per forward: one scale block per rmsnorm (2 per layer + final), two
     # reduction blocks per attention softmax
-    assert claims.get("rmsnorm", 0) >= 2 * CFG.n_layers + 1
-    assert claims.get("flash_attention", 0) >= 2 * CFG.n_layers
+    assert claims.get("rowblock", 0) >= 4 * CFG.n_layers + 1
 
 
-def test_explain_report_shows_claimant_decisions(lt):
+def test_explain_report_shows_rowblock_decisions(lt):
     lt.forward(TOKENS)
     rep = explain(lt.rt)
-    assert rep.backends == ("flash_attention", "rmsnorm", "mamba_scan",
-                            "pallas", "xla")
-    winners = {}
-    for blk in rep.blocks:
-        if blk.backend:
-            winners.setdefault(blk.backend, blk)
-    assert "rmsnorm" in winners and "flash_attention" in winners, \
-        sorted(winners)
-    blk = winners["rmsnorm"]
-    assert "rsqrt" in blk.opcodes
-    by_name = {v.backend: v for v in blk.verdicts}
-    assert by_name["rmsnorm"].claimed and by_name["rmsnorm"].winner
-    assert by_name["flash_attention"].reason == "no_softmax"
-    assert by_name["mamba_scan"].reason == "no_scan"
-    soft = winners["flash_attention"]
-    assert "reduce_max" in soft.opcodes or "reduce_sum" in soft.opcodes
+    assert rep.backends == ("rowblock", "pallas", "xla")
+    won = [blk for blk in rep.blocks if blk.backend == "rowblock"]
+    norm = next(blk for blk in won if "rsqrt" in blk.opcodes)
+    by_name = {v.backend: v for v in norm.verdicts}
+    assert by_name["rowblock"].claimed and by_name["rowblock"].winner
+    assert any("reduce_max" in blk.opcodes for blk in won)
+    assert any("reduce_sum" in blk.opcodes and "exp" in blk.opcodes
+               for blk in won)
+    # blocks outside the row patterns decline with the screen's slug
+    declined = [v for blk in rep.blocks for v in blk.verdicts
+                if v.backend == "rowblock" and not v.claimed]
+    assert declined and {v.reason for v in declined} >= {"no_row_pattern"}
 
 
 def test_prefill_and_decode_bitwise_identical_to_jitted_serving(params, lt):
-    ref_logits, ref_caches = jax.jit(
+    """Prefill logits and KV caches, then three decode steps, each within
+    ``LOGIT_ERR`` of the jitted direct serving path."""
+    ref_logits, ref_caches = _jit_highest(
         lambda p, t: T.serve_prefill(p, t, CFG, MAX_SEQ))(params, TOKENS)
+    lt.rt.executor.reset_stats()
     got = lt.prefill(TOKENS, MAX_SEQ)
-    assert np.asarray(ref_logits).tobytes() == got.tobytes()
+    assert _rel(got, ref_logits) <= LOGIT_ERR
+    # the tiny prefill's blocks by backend: rowblock takes its 4 softmax
+    # and 5 rmsnorm blocks
+    assert _claims(lt.rt) == {"rowblock": 9, "pallas": 20, "xla": 22}
 
     unit, n_groups = CFG.scan_groups()
     cache_np = lt.cache_numpy()
@@ -92,29 +115,26 @@ def test_prefill_and_decode_bitwise_identical_to_jitted_serving(params, lt):
     for g in range(n_groups):
         for i in range(len(unit)):
             gk, gv = cache_np[li]
-            assert np.asarray(ref_caches[f"l{i}"]["k"])[g].tobytes() \
-                == gk.tobytes()
-            assert np.asarray(ref_caches[f"l{i}"]["v"])[g].tobytes() \
-                == gv.tobytes()
+            assert _rel(gk, np.asarray(ref_caches[f"l{i}"]["k"])[g]) \
+                <= LOGIT_ERR
+            assert _rel(gv, np.asarray(ref_caches[f"l{i}"]["v"])[g]) \
+                <= LOGIT_ERR
             li += 1
 
-    dec = jax.jit(lambda p, c, t: T.serve_decode(p, c, t, CFG))
+    dec = _jit_highest(lambda p, c, t: T.serve_decode(p, c, t, CFG))
     caches = ref_caches
     for step in range(3):
         tok = np.asarray([[5 + step], [11 + step]], np.int32)
         ref_l, caches = dec(params, caches, tok)
         got_l = lt.decode(tok)
-        assert np.asarray(ref_l).tobytes() == got_l.tobytes(), \
-            f"decode step {step} diverged"
-    claims = _claims(lt.rt)
-    assert claims.get("rmsnorm", 0) >= 1
-    assert claims.get("flash_attention", 0) >= 1
+        assert _rel(got_l, ref_l) <= LOGIT_ERR, f"decode step {step}"
+    assert _claims(lt.rt).get("rowblock", 0) >= 1
 
 
 def test_lm_fuzz_grammars_cover_all_claimants():
-    """One seed per LMProgram grammar: claimant stack == XLA stack bitwise,
-    and each grammar's claimant actually claims (moe: gather on the XLA
-    floor, bitwise only)."""
+    """One seed per LMProgram grammar: the lm stack agrees with the XLA
+    stack to ``tapegen.LM_RTOL``, and ``rowblock`` claims a block of every
+    grammar but moe (gathers on the XLA floor)."""
     from repro.testing.tapegen import LMProgram, check_lm
     grammars = {LMProgram(seed).grammar for seed in range(4)}
     assert grammars == {"rmsnorm", "attention", "moe", "scan"}

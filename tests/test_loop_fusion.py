@@ -56,6 +56,25 @@ def test_steady_state_defers_and_drains():
     assert "exec" in drains[0]
 
 
+def test_loop_dispatch_counts_with_its_cache_lookup():
+    """A loop executable's dispatch counts once in ``blocks_run``, with its
+    executable-cache lookup, so hits + misses == blocks_run across
+    per-flush and loop-fused execution alike."""
+    with fresh_runtime(loop_fusion=True, loop_threshold=3,
+                       loop_unroll=32) as rt:
+        x = bh.full(256, 1.0)
+        bh.flush()
+        for _ in range(10):
+            x = _step(x)
+            bh.flush()
+        x.numpy()
+        st = rt.executor.snapshot_stats()
+        x._alive = False
+    assert st["loop_flushes"] == 1
+    assert st["exec_cache_hits"] + st["exec_cache_misses"] \
+        == st["blocks_run"]
+
+
 def test_deferred_entries_carry_pending_depth():
     _, hist = _run_chain(6, loop_fusion=True, loop_threshold=2,
                          loop_unroll=32)

@@ -167,7 +167,7 @@ def test_reduction_4096x4096(one_chip, axis):
 
 def test_rmsnorm_rowblock_8192x2560(one_chip):
     """The residual + rmsnorm scale chain of a d_model-2560 model over 8192
-    tokens, lowered by the rmsnorm claimant's row-replay kernel."""
+    tokens, lowered by the ``rowblock`` backend's row-replay kernel."""
     b, s, d = 4, 2048, 2560
 
     def record(rt):
@@ -180,7 +180,7 @@ def test_rmsnorm_rowblock_8192x2560(one_chip):
         return [x, g1, y]
 
     blocks = _blocks(_traced(record), LM_STACK)
-    assert "rmsnorm" in [name for name, _, _ in blocks]
+    assert "rowblock" in [name for name, _, _ in blocks]
     for name, ops, plan in blocks:
         _compile(one_chip, name, ops, plan)
 
@@ -201,7 +201,7 @@ def test_softmax_rowblock_40960x512(one_chip):
         return [sc, mask, neg, p]
 
     blocks = _blocks(_traced(record), LM_STACK)
-    assert "flash_attention" in [name for name, _, _ in blocks]
+    assert "rowblock" in [name for name, _, _ in blocks]
     for name, ops, plan in blocks:
         _compile(one_chip, name, ops, plan)
 
@@ -220,7 +220,7 @@ def test_vmem_budget_edge(one_chip):
     fits = softmax_rows(64, 32768)
     from repro.kernels.fused_block.rowblock import rowblock_lower_reason
     assert rowblock_lower_reason(fits) is None
-    _compile(one_chip, "flash_attention", fits)
+    _compile(one_chip, "rowblock", fits)
     assert rowblock_lower_reason(softmax_rows(64, 151936)) == "vmem"
 
 
@@ -228,8 +228,8 @@ def test_routing_blocks_at_deepseek_v2_lite_widths(one_chip):
     """The expert layer's opaque blocks of a 2048-token prefill: the top-6
     sort of 2048 x 64 router scores, the sort of the 12288 assignments, and
     the grouped product of their rows with 16 held experts of 2048 x 1408,
-    declined by every kernel claimant and compiled by XLA (the product to
-    the TPU's ``ragged-dot`` kernel)."""
+    declined by ``rowblock`` and ``pallas`` and compiled by XLA (the
+    product to the TPU's ``ragged-dot`` kernel)."""
     n, e, a, d, g, f = 2048, 64, 12288, 2048, 16, 1408
     s, o = BaseArray(n * e, F32), BaseArray(n * e, F32)
     k, p = BaseArray(a, F32), BaseArray(a, F32)
@@ -271,15 +271,18 @@ def test_float64_gather_and_unlowerable_opcodes_declined():
         assert pallas.claims(ops, None, COMPILED) == slug
         assert pallas.claims(ops, None, LoweringContext(interpret=True)) \
             is None
-    # the row-replay claimants apply the same screen
+    # the row replay applies the same screen to a block of its pattern
     r, c = 8, 128
-    x, m, o = BaseArray(r * c, np.float64), BaseArray(r, np.float64), \
-        BaseArray(r * c, np.float64)
-    soft64 = [Op("reduce_max", _v(m, (r,)), (_v(x, (r, c)),), axis=1),
-              Op("sub", _v(o, (r, c)), (_v(x, (r, c)), View(m, 0, (r, c),
-                                                             (1, 0))))]
-    assert get_backend("flash_attention").claims(soft64, None, COMPILED) \
-        in ("mosaic_x64", "no_softmax")
+    x, m, o, z = BaseArray(r * c, np.float64), BaseArray(r, np.float64), \
+        BaseArray(r * c, np.float64), BaseArray(r, np.float64)
+    exp_sum64 = [Op("sub", _v(o, (r, c)), (_v(x, (r, c)), View(m, 0, (r, c),
+                                                               (1, 0)))),
+                 Op("exp", _v(o, (r, c)), (_v(o, (r, c)),)),
+                 Op("reduce_sum", _v(z, (r,)), (_v(o, (r, c)),), axis=1)]
+    rowblock = get_backend("rowblock")
+    assert rowblock.claims(exp_sum64, None, COMPILED) == "mosaic_x64"
+    assert rowblock.claims(exp_sum64, None,
+                           LoweringContext(interpret=True)) is None
 
 
 OPCODES = sorted(set(_UNARY) - MOSAIC_OPCODES) \
