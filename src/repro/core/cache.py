@@ -60,33 +60,60 @@ def op_struct(op: Op) -> Tuple[Tuple, Tuple[int, ...]]:
     cached = op.__dict__.get("_sig_struct")
     if cached is not None:
         return cached
+    # the walk is inlined (no per-op closures): it runs once for every op
+    # of every fresh tape, before the merge cache can hit.  The base's
+    # shape is its buffer's (ir.BaseArray): an executable traced for one
+    # buffer shape is never replayed on another
     local: dict = {}
-
-    def li(uid: int) -> int:
-        return local.setdefault(uid, len(local))
-
-    def vk(v: View) -> Tuple:
-        # the base's shape is its buffer's (ir.BaseArray): an executable
-        # traced for one buffer shape is never replayed on another
-        return (li(v.base.uid), v.base.size, _dt(v.base.dtype), v.offset,
-                v.shape, v.strides, v.base.shape)
-
-    ins = tuple(vk(v) if isinstance(v, View) else ("lit", float(v))
-                for v in op.inputs)
-    out = vk(op.out) if op.out is not None else None
+    ins = []
+    for v in op.inputs:
+        if isinstance(v, View):
+            b = v.base
+            ins.append((local.setdefault(b.uid, len(local)), b.size,
+                        _dt(b.dtype), v.offset, v.shape, v.strides, b.shape))
+        else:
+            ins.append(("lit", float(v)))
+    v = op.out
+    out = None
+    if v is not None:
+        b = v.base
+        out = (local.setdefault(b.uid, len(local)), b.size, _dt(b.dtype),
+               v.offset, v.shape, v.strides, b.shape)
     # Set-carried bases (new/del/sync) get deterministic local indices by
     # ascending uid — frozenset iteration order must never leak into the
     # signature.  Size/dtype ride along for del/sync (the executor's
     # DEL/SYNC bookkeeping is part of a block's observable behaviour).
-    new = tuple(li(b.uid) for b in sorted(op.new_bases, key=lambda b: b.uid))
-    dels = tuple(li(b.uid) for b in sorted(op.del_bases, key=lambda b: b.uid))
-    delsync = tuple((li(b.uid), b.size, _dt(b.dtype)) for b in
-                    sorted((*op.del_bases, *op.sync_bases),
-                           key=lambda b: b.uid))
-    template = (op.opcode, out, ins, op.axis, new, dels, delsync)
+    new = dels = delsync = ()
+    if op.new_bases:
+        new = tuple(local.setdefault(b.uid, len(local))
+                    for b in sorted(op.new_bases, key=_BY_UID))
+    if op.del_bases or op.sync_bases:
+        dels = tuple(local.setdefault(b.uid, len(local))
+                     for b in sorted(op.del_bases, key=_BY_UID))
+        delsync = tuple((local.setdefault(b.uid, len(local)), b.size,
+                         _dt(b.dtype))
+                        for b in sorted((*op.del_bases, *op.sync_bases),
+                                        key=_BY_UID))
+    template = (op.opcode, out, tuple(ins), op.axis, new, dels, delsync)
     struct = (template, tuple(local))      # dict preserves insertion order
     op.__dict__["_sig_struct"] = struct
     return struct
+
+
+def canonical_signature(ops: Sequence[Op]) -> Tuple[Tuple,
+                                                    Tuple[int, ...]]:
+    """``(block_signature(ops), canon)``: the signature and the base uid of
+    each canonical index (``canon[i]`` is the uid numbered ``i``, in order
+    of first occurrence).  Two sequences with equal signatures are the same
+    ops under the renaming ``canon_a[i] -> canon_b[i]``, which is what lets
+    the scheduler replay a cached schedule by rebinding its uids."""
+    remap: dict = {}
+    sig: List[Tuple] = []
+    for op in ops:
+        template, bases = op_struct(op)
+        sig.append((template,
+                    tuple(remap.setdefault(u, len(remap)) for u in bases)))
+    return tuple(sig), tuple(remap)
 
 
 def block_signature(ops: Sequence[Op]) -> Tuple:
@@ -94,13 +121,7 @@ def block_signature(ops: Sequence[Op]) -> Tuple:
     merge-cache identity): each op's memoized template plus its base uids
     renumbered by first occurrence across the sequence, so loop iterations
     with fresh bases share executables."""
-    remap: dict = {}
-    sig: List[Tuple] = []
-    for op in ops:
-        template, bases = op_struct(op)
-        sig.append((template,
-                    tuple(remap.setdefault(u, len(remap)) for u in bases)))
-    return tuple(sig)
+    return canonical_signature(ops)[0]
 
 
 def _shard_digest(tape: Sequence[Op]) -> Tuple:
@@ -108,9 +129,28 @@ def _shard_digest(tape: Sequence[Op]) -> Tuple:
     Distributed plans are placement-dependent: the comm cost model prices
     shard counts and the resharding pass shapes the tape around them, so two
     structurally-equal tapes with different ShardSpecs must never share a
-    cache entry."""
-    from .dist.spec import placement_digest   # local: cache loads pre-dist
+    cache entry.  Until a ShardSpec has ever been made every entry would be
+    ``None`` (and the signature already fixes how many there are), so the
+    walk is skipped and the digest is ``()``; keys made before the first
+    sharding then simply miss after it."""
+    from .dist.spec import placement_digest, sharding_ever_used
+    if not sharding_ever_used():
+        return ()
     return placement_digest(tape)
+
+
+def tape_key(tape: Sequence[Op], algorithm: str, cost_model: str,
+             topology: Tuple = (), backends: Tuple = (),
+             cost_token: Tuple = (),
+             partition_backend: str = "greedy") -> Tuple[Tuple,
+                                                         Tuple[int, ...]]:
+    """``(tape_signature(...), canon)`` from one walk of the tape, where
+    ``canon`` is :func:`canonical_signature`'s uid vector: the merge-cache
+    key and what a hit rebinds its cached schedule with."""
+    sig, canon = canonical_signature(tape)
+    return (algorithm, cost_model, tuple(cost_token), tuple(topology),
+            tuple(backends), _shard_digest(tape), sig,
+            partition_backend), canon
 
 
 def tape_signature(tape: Sequence[Op], algorithm: str, cost_model: str,
@@ -129,10 +169,10 @@ def tape_signature(tape: Sequence[Op], algorithm: str, cost_model: str,
     ``partition_backend`` (greedy vs ilp solver) is appended LAST: the
     plan store's envelope reads ``key[2]`` positionally for its
     epoch-sensitivity flag, so new key components must never shift the
-    prefix."""
-    return (algorithm, cost_model, tuple(cost_token), tuple(topology),
-            tuple(backends), _shard_digest(tape), block_signature(tape),
-            partition_backend)
+    prefix.  Position 5 is the placement digest (``()`` until sharding is
+    first used)."""
+    return tape_key(tape, algorithm, cost_model, topology, backends,
+                    cost_token, partition_backend)[0]
 
 
 def tapes_structurally_equal(a: Sequence[Op], b: Sequence[Op]) -> bool:
@@ -393,11 +433,12 @@ class MergeCache:
     """LRU: a steady mix of hot tapes (training step + eval step + logging
     flush) stays resident even when one-off tapes churn past capacity.
 
-    Values are opaque to the cache; the scheduler stores ``(op_blocks,
-    lowering_decisions)`` tuples (immutable nested tuples) so a hit skips
-    both the partitioner (stage 3) and backend probing (stage 5), and loop
+    Values are opaque to the cache; the scheduler stores a
+    ``scheduler.PlanEntry`` per tape key (the lowered schedule a hit
+    rebinds, so a hit skips the partitioner, the schedule's per-block
+    analysis and backend probing alike), and loop
     plans (DESIGN.md §16) live beside them under a ``("loop",) + key``
-    prefix.
+    prefix.  Whatever an entry holds is evicted with it.
 
     Thread-safe (DESIGN.md §18): one re-entrant lock serializes lookups,
     insertions and the LRU reorder — N sessions flushing concurrently share

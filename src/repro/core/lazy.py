@@ -276,6 +276,7 @@ class Runtime:
                 else:
                     entry = {"n_ops": len(tape), "cached": True,
                              **sched.stats}
+                entry["replayed"] = sched.replayed
                 entry["merge_hits"] = self.cache.hits - h0
                 entry["merge_misses"] = self.cache.misses - m0
                 fsp.set(n_blocks=len(sched.blocks),

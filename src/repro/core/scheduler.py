@@ -33,10 +33,12 @@ multi-device blocks in stage 5 — lowering them through ``jax.shard_map``
 with explicit collectives while other blocks run on ``pallas``/``xla``
 unchanged.
 
-Stages 3 and 5 are skipped on a merge-cache hit (§IV-F): the cache maps a
-canonical tape signature (+ lowering policy) to the block structure AND the
-per-block lowering decisions, so iterative programs pay the partition and
-backend-probing costs once and only re-run the cheap linear schedule stage.
+Stages 2-5 are skipped on a merge-cache hit (§IV-F): the cache maps a
+canonical tape signature (+ lowering policy) to a :class:`PlanEntry` that
+holds the block structure, the per-block lowering decisions and the lowered
+schedule itself with its base uids written as canonical indices.  A hit
+costs the key's one walk of the tape plus a rebind of those indices to the
+fresh tape's uids — nothing per block is re-derived.
 """
 
 from __future__ import annotations
@@ -47,7 +49,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 from .algorithms import PartitionResult, partition
 from .backends import LoweringDecision, LoweringPolicy, select_lowering
-from .cache import MergeCache, tape_signature
+from .cache import MergeCache, tape_key
 from .cost import make_cost_model, model_cache_token
 from .executor import block_dead_bases, block_io, block_signature
 from .ir import Op
@@ -78,6 +80,46 @@ class Schedule:
     result: Optional[PartitionResult] = None   # None on a merge-cache hit
     stats: Dict[str, float] = field(default_factory=dict)
     key: Optional[Tuple] = None                # merge-cache key (use_cache)
+    replayed: bool = False                     # rebound from a memory hit
+
+
+class PlanEntry:
+    """One merge-cache value (DESIGN.md §7).
+
+    ``template`` is the lowered schedule of the tape that made the entry,
+    with every uid in ``inputs``/``outputs``/``contracted`` replaced by its
+    canonical index (``cache.canonical_signature``); its ``op_indices`` and
+    lowerings are the block lists and decisions the plan store persists.
+    ``digest`` is the key's trace digest, made once, the first time a
+    tracer asks for it.
+
+    :meth:`replay` is exact: a key hit means the fresh tape is the entry's
+    tape under the renaming ``canon_entry[i] -> canon[i]``, and everything
+    ``plan_blocks`` derives is either structural (``op_indices``,
+    ``donatable`` positions, ``signature``, ``has_work``, the lowering) or
+    a list of uids in first-occurrence order, which commutes with a
+    renaming."""
+
+    __slots__ = ("template", "digest")
+
+    def __init__(self, plans: Sequence[BlockPlan], canon: Sequence[int],
+                 digest: Optional[str] = None):
+        index = {u: i for i, u in enumerate(canon)}.__getitem__
+        self.template = tuple(
+            (p.op_indices, tuple(map(index, p.inputs)),
+             tuple(map(index, p.outputs)), tuple(map(index, p.contracted)),
+             p.donatable, p.signature, p.has_work, p.lowering)
+            for p in plans)
+        self.digest = digest
+
+    def replay(self, canon: Sequence[int]) -> List[BlockPlan]:
+        """The template's plans with canonical indices bound to ``canon``,
+        the fresh tape's uid vector."""
+        uid = canon.__getitem__
+        return [BlockPlan(ops, tuple(map(uid, ins)), tuple(map(uid, outs)),
+                          tuple(map(uid, con)), don, sig, work, low)
+                for ops, ins, outs, con, don, sig, work, low
+                in self.template]
 
 
 @dataclass(frozen=True)
@@ -175,10 +217,14 @@ class Scheduler:
         (stage 5).  ``topology`` is the executor's device/mesh key so
         cached partitions are never reused across different placements;
         the policy's backend names are part of the key too, so decisions
-        made for one backend stack never leak into another.  On a
-        merge-cache hit both the partition AND the lowering decisions are
-        replayed — steady-state flushes skip partitioning and backend
-        probing alike (``Schedule.result`` is ``None`` on a hit).
+        made for one backend stack never leak into another.  On an
+        in-memory merge-cache hit the entry's schedule is replayed
+        (:meth:`PlanEntry.replay`): steady-state flushes skip partitioning,
+        the per-block schedule analysis and backend probing alike
+        (``Schedule.result`` is ``None`` and ``Schedule.replayed`` true; the
+        ``stage.schedule`` span times the rebind and no ``stage.lower``
+        opens).  A plan-store hit rebuilds the schedule once from the
+        stored blocks and decisions and is replayed from memory after.
         ``Schedule.stats`` carries per-stage timings.
 
         ``partition_backend='ilp'`` solves the partition as an anytime
@@ -190,10 +236,11 @@ class Scheduler:
         blocks: Optional[Tuple[Tuple[int, ...], ...]] = None
         decisions: Optional[Tuple] = None
         key: Optional[Tuple] = None
-        cached = False
+        canon: Tuple[int, ...] = ()
+        digest: Optional[str] = None
         if use_cache:
             with trace.span("plan.lookup") as sp:
-                key = tape_signature(
+                key, canon = tape_key(
                     tape, algorithm, cost_model, topology=topology,
                     backends=lowering.key() if lowering else (),
                     cost_token=model_cache_token(cost_model),
@@ -203,18 +250,28 @@ class Scheduler:
                 if entry is None:
                     hit = "miss"
                     if self.plan_store is not None:
-                        entry = self.plan_store.load(key)
-                        if entry is not None:
-                            # promote the disk hit so later flushes stay
-                            # in memory
+                        stored = self.plan_store.load(key)
+                        if stored is not None:
                             hit = "disk"
-                            self.cache.put(key, entry)
+                            blocks, decisions = stored
                 if trace.active() is not None:
-                    from .tuning.profile import signature_digest
-                    sp.set(hit=hit, key=signature_digest(key))
+                    digest = entry.digest if entry is not None else None
+                    if digest is None:
+                        from .tuning.profile import signature_digest
+                        digest = signature_digest(key)
+                        if entry is not None:
+                            entry.digest = digest
+                    sp.set(hit=hit, key=digest)
             if entry is not None:
-                blocks, decisions = entry
-                cached = True
+                t0 = time.perf_counter()
+                with trace.span("stage.schedule",
+                                n_blocks=len(entry.template), cached=True,
+                                replayed=True):
+                    plans = entry.replay(canon)
+                stats["t_schedule_s"] = time.perf_counter() - t0
+                return Schedule(tape=list(tape), blocks=plans, stats=stats,
+                                key=key, replayed=True)
+        cached = blocks is not None
         result = None
         if blocks is None:
             result = partition(tape, algorithm=algorithm,
@@ -226,7 +283,7 @@ class Scheduler:
             stats.update(result.stats)
         t0 = time.perf_counter()
         with trace.span("stage.schedule", n_blocks=len(blocks),
-                        cached=cached):
+                        cached=cached, replayed=False):
             plans = plan_blocks(tape, blocks)
         stats["t_schedule_s"] = time.perf_counter() - t0
         if lowering is not None:
@@ -238,9 +295,10 @@ class Scheduler:
                 plans = [replace(p, lowering=d) if d is not None else p
                          for p, d in zip(plans, decisions)]
             stats["t_lower_s"] = time.perf_counter() - t0
-        if use_cache and not cached:
-            self.cache.put(key, (blocks, decisions))
-            if self.plan_store is not None:
+        if use_cache:
+            # a disk hit is promoted too, so later flushes replay from memory
+            self.cache.put(key, PlanEntry(plans, canon, digest))
+            if self.plan_store is not None and not cached:
                 self.plan_store.store(key, blocks, decisions)
         return Schedule(tape=list(tape), blocks=plans, result=result,
                         stats=stats, key=key)

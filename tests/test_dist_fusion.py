@@ -321,6 +321,87 @@ def test_merge_cache_not_shared_across_topology():
     assert sch.cache.hits == 1
 
 
+def _doubling_tape():
+    with fresh_runtime() as rt:
+        x = bh.asarray(np.arange(8.0))
+        y = x * 2.0
+        y.rt.record(Op("sync", None, sync_bases=frozenset({y.view.base})))
+        tape = list(rt.tape)
+        rt.tape.clear()
+    return tape, x
+
+
+def test_merge_cache_misses_across_placements_once_sharding_is_used():
+    from repro.core.scheduler import Scheduler
+    tape, x = _doubling_tape()
+    sch = Scheduler()
+    x.view.base.shard_spec = ShardSpec.for_dim((8,), 0, "dev", 4)
+    sch.plan(tape, cost_model="comm")
+    x.view.base.shard_spec = ShardSpec.for_dim((8,), 0, "dev", 2)
+    sch.plan(tape, cost_model="comm")
+    assert sch.cache.misses == 2 and sch.cache.hits == 0
+    again = sch.plan(tape, cost_model="comm")
+    assert sch.cache.hits == 1 and again.replayed
+
+
+def test_unsharded_key_skips_the_placement_digest(monkeypatch, tmp_path):
+    """Before any ShardSpec exists the key's position 5 is ``()``; the
+    prefix the plan store reads positionally (``key[2]``, the cost model's
+    token) is where it was, and the digest comes back once sharding is
+    used."""
+    import json
+
+    import repro.core.dist.spec as spec_mod
+    from repro.core.cost import model_cache_token
+    from repro.core.serve import PlanStore
+
+    tape, _ = _doubling_tape()
+    monkeypatch.setattr(spec_mod, "_SPECS_SEEN", False)
+    store = PlanStore(str(tmp_path))
+    for model in ("bohrium", "calibrated"):
+        key = tape_signature(tape, "greedy", model,
+                             cost_token=model_cache_token(model))
+        assert key[5] == ()
+        assert key[2] == model_cache_token(model)
+        assert store.store(key, ((0,),), None)
+        with open(store.path_for(key)) as f:
+            assert json.load(f)["epoch_sensitive"] == bool(key[2])
+        assert store.load(key) == (((0,),), None)
+    unsharded = tape_signature(tape, "greedy", "comm")
+    monkeypatch.setattr(spec_mod, "_SPECS_SEEN", True)
+    sharded = tape_signature(tape, "greedy", "comm")
+    assert sharded[5] == dist.spec.placement_digest(tape) == (None, None)
+    assert sharded != unsharded
+    assert sharded[:5] + sharded[6:] == unsharded[:5] + unsharded[6:]
+
+
+def test_merge_cache_capacity_bounds_the_schedule_templates():
+    """Templates live inside the LRU entries: evicting an entry evicts its
+    template, so a later tape of the evicted structure is planned afresh."""
+    from repro.core.cache import MergeCache
+    from repro.core.scheduler import PlanEntry, Scheduler
+
+    tapes = []
+    for n in (4, 8, 16):
+        with fresh_runtime() as rt:
+            x = bh.asarray(np.arange(float(n)))
+            y = bh.sin(x) * 2.0
+            y.rt.record(Op("sync", None,
+                           sync_bases=frozenset({y.view.base})))
+            tapes.append(list(rt.tape))
+            rt.tape.clear()
+    sch = Scheduler(MergeCache(capacity=2))
+    scheds = [sch.plan(t) for t in tapes]
+    assert len(sch.cache) == 2 and sch.cache.evictions == 1
+    assert scheds[0].key not in sch.cache
+    for s in scheds[1:]:
+        entry = sch.cache._store[s.key]
+        assert isinstance(entry, PlanEntry)
+        assert len(entry.template) == len(s.blocks)
+    assert not sch.plan(tapes[0]).replayed          # evicted: planned again
+    assert sch.plan(tapes[0]).replayed
+
+
 # ---------------------------------------------------------------------------
 # DistBlockExecutor
 # ---------------------------------------------------------------------------

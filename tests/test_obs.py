@@ -333,6 +333,39 @@ class TestHostSpans:
         assert lookups[2]["hit"] == "memory"
         assert lookups[2]["key"] == lookups[1]["key"]
 
+    def test_hit_replays_without_redigesting_its_key(self, tracer,
+                                                     monkeypatch):
+        """A traced hit's ``stage.schedule`` says ``replayed=True`` and
+        opens no ``stage.lower``; its ``plan.lookup`` reads the key digest
+        its miss stored in the entry, so ``signature_digest`` never sees a
+        hit's key again.  A miss says ``replayed=False``."""
+        import repro.core.tuning.profile as profile
+        from repro.core.scheduler import Scheduler
+        digested, keys = [], []
+        real_digest, real_plan = profile.signature_digest, Scheduler.plan
+        monkeypatch.setattr(profile, "signature_digest",
+                            lambda sig: digested.append(sig)
+                            or real_digest(sig))
+
+        def plan(*a, **k):
+            sched = real_plan(*a, **k)
+            keys.append(sched.key)
+            return sched
+        monkeypatch.setattr(Scheduler, "plan", plan)
+        with fresh_runtime(loop_fusion=False) as rt:
+            for _ in range(4):
+                _chain(rt)
+        assert [e["args"]["hit"] for e in _events(tracer, "plan.lookup")] \
+            == ["miss", "miss", "memory", "memory"]
+        assert [e["args"]["replayed"]
+                for e in _events(tracer, "stage.schedule")] \
+            == [False, False, True, True]
+        assert len(_events(tracer, "stage.lower")) == 2
+        assert keys[1] == keys[2] == keys[3]
+        assert sum(d == keys[1] for d in digested) == 1
+        assert [h["replayed"] for h in rt.history] == [False, False, True,
+                                                       True]
+
     def test_plan_store_lookup_reads_disk(self, tracer, tmp_path):
         with fresh_runtime(loop_fusion=False, plan_store=str(tmp_path)) \
                 as rt:

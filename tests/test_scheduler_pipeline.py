@@ -334,3 +334,165 @@ def test_where_dtype_follows_value_branches():
         w = bh.where(i32 < j32, i32, j32)
         assert w.dtype == np.int32
         assert w.numpy().dtype == np.int32
+
+
+# ---------------------------------------------------------------------------
+# Merge-cache replay: a hit rebinds the cached schedule, and the rebound
+# schedule equals a fresh schedule + lower stage on the same tape
+# ---------------------------------------------------------------------------
+
+def _spy_replays(monkeypatch):
+    """Wrap ``Scheduler.plan``: for every replayed schedule, record it
+    beside what ``plan_blocks`` + ``lower_plans`` give on the same tape
+    from the same block lists."""
+    from dataclasses import replace
+
+    from repro.core.scheduler import Scheduler, lower_plans
+
+    seen = []
+    real = Scheduler.plan
+
+    def plan(self, tape, **kw):
+        sched = real(self, tape, **kw)
+        if sched.replayed:
+            fresh = plan_blocks(tape, [p.op_indices for p in sched.blocks])
+            lowering = kw.get("lowering")
+            if lowering is not None:
+                model = make_cost_model(kw.get("cost_model", "bohrium"))
+                fresh = [replace(p, lowering=d) if d is not None else p
+                         for p, d in zip(fresh, lower_plans(
+                             tape, fresh, lowering, model))]
+            seen.append((sched.blocks, fresh))
+        return sched
+
+    monkeypatch.setattr(Scheduler, "plan", plan)
+    return seen
+
+
+def _tiny_dense():
+    import jax
+
+    from repro.models import transformer as T
+    from repro.models.config import ModelConfig
+    from repro.models.lazy_transformer import LazyTransformer
+
+    cfg = ModelConfig(name="lm_tiny", family="dense", n_layers=2, d_model=32,
+                      n_heads=2, n_kv_heads=2, d_ff=64, vocab_size=97,
+                      dtype="float32", param_dtype="float32",
+                      norm_plus_one=True, tie_embeddings=False)
+    params, _ = T.init_params(cfg, jax.random.PRNGKey(0))
+    return LazyTransformer(params, cfg, backend="lm"), 97
+
+
+def _tiny_dsv2lite():
+    import json
+    from pathlib import Path
+
+    from bench.spec import load_module
+
+    bench = Path(__file__).resolve().parent.parent / "bench"
+    cfg = dict(json.loads((bench / "configs" / "dsv2lite.json").read_text()),
+               hidden_size=64, num_attention_heads=4, num_key_value_heads=4,
+               kv_lora_rank=16, qk_rope_head_dim=8, qk_nope_head_dim=16,
+               v_head_dim=16, intermediate_size=96, moe_intermediate_size=32,
+               n_routed_experts=4, held_experts=[0, 4], num_experts_per_tok=2,
+               n_shared_experts=1, num_hidden_layers=3, vocab_size=256)
+    return load_module("systems", "lazy_mla_moe", bench)._program(cfg, 7), 256
+
+
+@pytest.mark.parametrize("make", [_tiny_dense, _tiny_dsv2lite],
+                         ids=["dense", "dsv2lite"])
+def test_replayed_prefill_schedule_equals_a_fresh_one(make, monkeypatch):
+    """Short (S) and long (L) prompts in the orders S->S, S->L, L->L and
+    L->S, twice, so each order's key (it holds the previous prompt's
+    frees) is planned once and replayed once; the replayed logits are
+    bit-identical to the same prompt planned without the cache."""
+    import jax
+
+    with jax.default_matmul_precision("highest"):
+        lt, vocab = make()
+        seen = _spy_replays(monkeypatch)
+        rng = np.random.default_rng(3)
+        for n in (4, 4, 12, 12, 4) * 2:
+            tokens = rng.integers(0, vocab, (1, n), dtype=np.int32)
+            got = lt.prefill(tokens, 16)
+            if lt.rt.history[-1]["replayed"]:
+                lt.rt.use_cache = False
+                try:
+                    fresh = lt.prefill(tokens, 16)
+                finally:
+                    lt.rt.use_cache = True
+                assert got.tobytes() == fresh.tobytes()
+    assert len(seen) >= 4
+    for replayed, fresh in seen:
+        assert replayed == fresh
+
+
+def test_replayed_loop_body_schedule_equals_a_fresh_one(monkeypatch):
+    """A recurring numerical tape: its steady-state flushes replay, and
+    the loop fuser plans its body from a replayed schedule."""
+    seen = _spy_replays(monkeypatch)
+    with fresh_runtime(loop_fusion=True, loop_threshold=3,
+                       loop_unroll=8) as rt:
+        x = bh.full(64, 1.0)
+        bh.flush()
+        for _ in range(12):
+            y = bh.sin(x) * 0.5 + x * 0.25
+            x.delete()
+            x = y
+            bh.flush()
+        out = x.numpy()
+        assert rt.executor.stats["loop_iterations"] > 0
+    assert np.all(np.isfinite(out))
+    assert seen
+    for replayed, fresh in seen:
+        assert replayed == fresh
+
+
+def test_plan_store_hit_then_replay_in_a_fresh_scheduler(tmp_path,
+                                                        monkeypatch):
+    """A second process's runtime loads the plan from disk once (a disk
+    hit builds the schedule from the stored blocks and decisions) and
+    replays it from memory after."""
+    def program():
+        with fresh_runtime(loop_fusion=False, plan_store=str(tmp_path)) \
+                as rt:
+            vals = []
+            for _ in range(4):
+                x = bh.asarray(np.linspace(0.0, 1.0, 32))
+                vals.append(float((bh.sin(x) * 0.5 + x * 0.25).sum()
+                                  .numpy()))
+            return vals, list(rt.history)
+
+    cold, _ = program()
+    seen = _spy_replays(monkeypatch)
+    warm, hist = program()
+    assert warm == cold
+    assert hist[0]["cached"] and not hist[0]["replayed"]   # from disk
+    assert [h["replayed"] for h in hist[2:]] == [True, True]
+    assert len(seen) == 2
+    for replayed, fresh in seen:
+        assert replayed == fresh
+
+
+def test_hit_runs_no_per_block_planning(monkeypatch):
+    """A hit rebinds: no partition, no ``plan_blocks``, ``block_io``,
+    ``block_dead_bases``, per-block ``block_signature`` or
+    ``lower_plans``."""
+    import repro.core.scheduler as sched_mod
+    from repro.core.executor import BlockExecutor
+
+    tape = random_tape(5, n_actions=40)
+    policy = BlockExecutor(backend="pallas").lowering_policy()
+    s = sched_mod.Scheduler()
+    first = s.plan(tape, lowering=policy)
+    calls = []
+    for name in ("partition", "plan_blocks", "lower_plans", "block_io",
+                 "block_dead_bases", "block_signature", "replace"):
+        monkeypatch.setattr(sched_mod, name,
+                            lambda *a, _n=name, **k: calls.append(_n))
+    second = s.plan(tape, lowering=policy)
+    assert calls == []
+    assert second.replayed and not first.replayed
+    assert second.blocks == first.blocks
+    assert second.stats.keys() == {"t_schedule_s"}
